@@ -5,11 +5,16 @@ evaluation itself (the paper's artifact is the model, so its evaluation cost
 is the honest per-call number); ``derived`` carries the reproduced claim.
 
 Run: PYTHONPATH=src python -m benchmarks.run [--only fig1,table1]
+
+A module that raises is reported as an ``ERROR:`` row (with its traceback
+on stderr) and the run exits non-zero after the remaining modules.
 """
 from __future__ import annotations
 
 import argparse
+import sys
 import time
+import traceback
 
 from benchmarks import common
 
@@ -23,6 +28,7 @@ def main() -> None:
     args = ap.parse_args()
     only = set(filter(None, args.only.split(",")))
 
+    failed = []
     for name in MODULES:
         if only and name not in only:
             continue
@@ -30,12 +36,15 @@ def main() -> None:
         t0 = time.perf_counter()
         try:
             derived = mod.run(common.emit)
-        except Exception as e:  # keep the harness alive; report the failure
+        except Exception as e:  # report it, run the rest, then fail
+            traceback.print_exc()
             derived = f"ERROR:{type(e).__name__}:{e}"
+            failed.append(name)
         us = (time.perf_counter() - t0) * 1e6
         common.emit(f"{name}.total", us, derived)
     common.flush()
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

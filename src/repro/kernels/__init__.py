@@ -6,7 +6,8 @@ decode_attention — memory-bound KV-cache attention (bf16/int8 KV): the
                    realizing its "shrink attention traffic" insight on TPU,
                    plus a paged variant that gathers physical KV pages via a
                    scalar-prefetched page table (continuous batching)
-ops              — jit'd wrappers with XLA fallbacks
+ops              — the entries the model calls: eligibility rules that
+                   raise on an ineligible shape, interpret mode on CPU
 ref              — pure-jnp oracles
 """
 from repro.kernels import decode_attention, flash_attention, ops, ref

@@ -18,7 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jax_compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -29,8 +28,10 @@ def _online_softmax_step(q, k, v, valid, base_pos, scale,
 
     q: (rows, dh) f32; k/v: (bkv, dh) f32 (already dequantized); ``valid``
     masks KV at absolute position >= valid — a scalar for a shared limit or
-    a (rows, 1) array for per-row (causal) limits. Shared by the
-    dense-cache decode, paged decode, and chunk-prefill kernels."""
+    a (rows, 1) array for per-row (causal) limits. The running max and sum
+    live in (rows, 1) scratch: Mosaic lays out 2-D tiles, not 1-D vectors.
+    Shared by the dense-cache decode, paged decode, and chunk-prefill
+    kernels."""
     bkv = k.shape[0]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -39,12 +40,12 @@ def _online_softmax_step(q, k, v, valid, base_pos, scale,
     s = jnp.where(kpos < valid, s, NEG_INF)
     m_prev = m_scr[...]
     l_prev = l_scr[...]
-    m_new = jnp.maximum(m_prev, s.max(axis=-1))
-    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, None]))
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
     corr = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))
     m_scr[...] = m_new
-    l_scr[...] = l_prev * corr + p.sum(axis=-1)
-    acc_scr[...] = (acc_scr[...] * corr[:, None]
+    l_scr[...] = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    acc_scr[...] = (acc_scr[...] * corr
                     + jax.lax.dot(p.astype(jnp.float32), v,
                                   preferred_element_type=jnp.float32))
 
@@ -57,19 +58,28 @@ def _init_scratch(m_scr, l_scr, acc_scr):
 
 def _finalize(o_ref, l_scr, acc_scr):
     l = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0, 0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
+    o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
+def _scratch(rows: int, dh: int):
+    """Running max, running sum and accumulator of one (rows, dh) q block."""
+    return [pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, dh), jnp.float32)]
 
 
 def _kernel(valid_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
             m_scr, l_scr, acc_scr, *, scale: float, block_kv: int,
             n_kv: int, quantized: bool):
+    b = pl.program_id(0)
+    h = pl.program_id(1)
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
     def _init():
         _init_scratch(m_scr, l_scr, acc_scr)
 
-    valid = valid_ref[0]
+    valid = valid_ref[b]
     run = ki * block_kv < valid
 
     @pl.when(run)
@@ -78,14 +88,19 @@ def _kernel(valid_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref,
         k = k_ref[0, 0].astype(jnp.float32)            # (bkv, dh)
         v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k = k * ksc_ref[0]
-            v = v * vsc_ref[0]
+            k = k * ksc_ref[h]
+            v = v * vsc_ref[h]
         _online_softmax_step(q, k, v, valid, ki * block_kv, scale,
                              m_scr, l_scr, acc_scr)
 
     @pl.when(ki == n_kv - 1)
     def _out():
         _finalize(o_ref, l_scr, acc_scr)
+
+
+# whole-array SMEM operand (lengths, per-head scales): Mosaic accepts a
+# rank-1 SMEM block only at full size, so kernels index it by program id
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def decode_attention(q, k_cache, v_cache, kv_valid, *, scale: float = None,
@@ -123,24 +138,15 @@ def decode_attention(q, k_cache, v_cache, kv_valid, *, scale: float = None,
         kern,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, ki: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, ki: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, ki: (h,),
-                         memory_space=pltpu.SMEM),
+            _SMEM, _SMEM, _SMEM,
             pl.BlockSpec((1, 1, group, dh), lambda b, h, ki: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, ki: (b, h, ki, 0)),
             pl.BlockSpec((1, 1, block_kv, dh), lambda b, h, ki: (b, h, ki, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh), lambda b, h, ki: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, dh), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, dh), jnp.float32),
-        ],
-        compiler_params=_CompilerParams(
+        scratch_shapes=_scratch(group, dh),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(kv_valid.astype(jnp.int32), k_scale.astype(jnp.float32),
@@ -152,6 +158,7 @@ def _paged_kernel(pt_ref, len_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref,
                   o_ref, m_scr, l_scr, acc_scr, *, scale: float,
                   page_size: int, n_pages_per_seq: int, quantized: bool):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
@@ -164,11 +171,11 @@ def _paged_kernel(pt_ref, len_ref, ksc_ref, vsc_ref, q_ref, k_ref, v_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)            # (group, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page_size, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page_size, dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k = k * ksc_ref[0]
-            v = v * vsc_ref[0]
+            k = k * ksc_ref[h]
+            v = v * vsc_ref[h]
         _online_softmax_step(q, k, v, valid, pi * page_size, scale,
                              m_scr, l_scr, acc_scr)
 
@@ -182,11 +189,13 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
                            interpret: bool = False):
     """Decode attention over a page-table-indirected KV cache.
 
-    q: (B, H, dh); k/v_pages: (n_pages, page_size, Hkv, dh) pooled pages
-    (int8 when scales given); page_table: (B, n_pages_per_seq) int32 physical
-    page ids (entries past a sequence's last used page may point anywhere —
-    typically the reserved null page 0 — and are masked by ``seq_lens``);
-    seq_lens: (B,) int32 valid tokens per sequence -> (B, H, dh).
+    q: (B, H, dh); k/v_pages: (n_pages, Hkv, page_size, dh) pooled pages,
+    head-major so that one (page, head) block is a (page_size, dh) tile
+    (int8 when scales given); page_table: (B, n_pages_per_seq) int32
+    physical page ids (entries past a sequence's last used page may point
+    anywhere — typically the reserved null page 0 — and are masked by
+    ``seq_lens``); seq_lens: (B,) int32 valid tokens per sequence -> (B,
+    H, dh).
 
     The page table is a scalar-prefetch operand: the BlockSpec ``index_map``
     reads it to gather each sequence's physical KV pages, so the kernel
@@ -194,7 +203,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
     tiling, with one extra level of indirection for continuous batching).
     """
     B, H, dh = q.shape
-    n_pages, page_size, Hkv = k_pages.shape[:3]
+    n_pages, Hkv, page_size = k_pages.shape[:3]
     n_pp = page_table.shape[1]
     group = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
@@ -211,30 +220,23 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
         num_scalar_prefetch=2,        # page_table, seq_lens
         grid=(B, Hkv, n_pp),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, pi, pt, ln: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, pi, pt, ln: (h,),
-                         memory_space=pltpu.SMEM),
+            _SMEM, _SMEM,
             pl.BlockSpec((1, 1, group, dh),
                          lambda b, h, pi, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, pi, pt, ln: (pt[b, pi], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, pi, pt, ln: (pt[b, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, dh),
+                         lambda b, h, pi, pt, ln: (pt[b, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dh),
+                         lambda b, h, pi, pt, ln: (pt[b, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, group, dh),
                                lambda b, h, pi, pt, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group,), jnp.float32),
-            pltpu.VMEM((group, dh), jnp.float32),
-        ],
+        scratch_shapes=_scratch(group, dh),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, group, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
@@ -248,6 +250,7 @@ def _chunk_kernel(pt_ref, start_ref, len_ref, ksc_ref, vsc_ref, q_ref,
                   scale: float, page_size: int, n_pages_per_seq: int,
                   chunk: int, group: int, quantized: bool):
     b = pl.program_id(0)
+    h = pl.program_id(1)
     pi = pl.program_id(2)
 
     @pl.when(pi == 0)
@@ -263,11 +266,11 @@ def _chunk_kernel(pt_ref, start_ref, len_ref, ksc_ref, vsc_ref, q_ref,
     @pl.when(run)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)            # (chunk*group, dh)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (page_size, dh)
-        v = v_ref[0, :, 0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)            # (page_size, dh)
+        v = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k = k * ksc_ref[0]
-            v = v * vsc_ref[0]
+            k = k * ksc_ref[h]
+            v = v * vsc_ref[h]
         # per-row causal limit: query row r sits at absolute position
         # start + r // group and may attend KV positions <= its own,
         # clipped to the chunk's true (unpadded) extent
@@ -287,12 +290,13 @@ def chunk_prefill_attention(q, k_pages, v_pages, page_table, start, n_valid,
     """Chunked-prefill attention: a q-block against a page-table KV cache.
 
     q: (B, C, H, dh) — one fixed-size prefill chunk whose queries sit at
-    absolute positions [start, start + C); k/v_pages: (n_pages, page_size,
-    Hkv, dh) pooled pages (int8 when scales given) ALREADY containing the
-    chunk's own KV at those positions; page_table: (B, n_pages_per_seq)
-    int32 physical page ids; start: scalar or (B,) int32 first absolute
-    position of the chunk; n_valid: (B,) int32 total valid tokens once this
-    chunk lands (masks the chunk's right-padding). Returns (B, C, H, dh).
+    absolute positions [start, start + C); k/v_pages: (n_pages, Hkv,
+    page_size, dh) head-major pooled pages (int8 when scales given)
+    ALREADY containing the chunk's own KV at those positions; page_table:
+    (B, n_pages_per_seq) int32 physical page ids; start: scalar or (B,)
+    int32 first absolute position of the chunk; n_valid: (B,) int32 total
+    valid tokens once this chunk lands (masks the chunk's right-padding).
+    Returns (B, C, H, dh).
 
     Each query attends causally — KV positions <= its own — across every
     page the sequence owns, so a chunk sees the whole cached prefix (shared
@@ -303,7 +307,7 @@ def chunk_prefill_attention(q, k_pages, v_pages, page_table, start, n_valid,
     diagonal-band block skipping of the dense prefill kernel.
     """
     B, C, H, dh = q.shape
-    n_pages, page_size, Hkv = k_pages.shape[:3]
+    n_pages, Hkv, page_size = k_pages.shape[:3]
     n_pp = page_table.shape[1]
     group = H // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
@@ -324,30 +328,23 @@ def chunk_prefill_attention(q, k_pages, v_pages, page_table, start, n_valid,
         num_scalar_prefetch=3,        # page_table, start, n_valid
         grid=(B, Hkv, n_pp),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, pi, pt, st, ln: (h,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda b, h, pi, pt, st, ln: (h,),
-                         memory_space=pltpu.SMEM),
+            _SMEM, _SMEM,
             pl.BlockSpec((1, 1, C * group, dh),
                          lambda b, h, pi, pt, st, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, pi, pt, st, ln: (pt[b, pi], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, dh),
-                         lambda b, h, pi, pt, st, ln: (pt[b, pi], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, dh),
+                         lambda b, h, pi, pt, st, ln: (pt[b, pi], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, dh),
+                         lambda b, h, pi, pt, st, ln: (pt[b, pi], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, C * group, dh),
                                lambda b, h, pi, pt, st, ln: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C * group,), jnp.float32),
-            pltpu.VMEM((C * group,), jnp.float32),
-            pltpu.VMEM((C * group, dh), jnp.float32),
-        ],
+        scratch_shapes=_scratch(C * group, dh),
     )
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, C * group, dh), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table.astype(jnp.int32), start, n_valid.astype(jnp.int32),
@@ -389,16 +386,21 @@ def spec_verify_attention(q, k_pages, v_pages, page_table, seq_lens, n_fed,
                                    v_scale=v_scale, interpret=interpret)
 
 
-def quantize_kv(k, v):
+def quantize_kv(k, v, *, head_axis: int = 2):
     """Per-kv-head symmetric int8 quantization of a KV cache.
 
-    k/v: (B, L, Hkv, dh) -> (k_i8, v_i8, k_scale, v_scale)."""
+    k/v: (B, L, Hkv, dh), or a head-major page pool (n_pages, Hkv,
+    page_size, dh) with ``head_axis=1`` -> (k_i8, v_i8, k_scale, v_scale)
+    with (Hkv,) scales."""
     def one(x):
-        amax = jnp.maximum(jnp.abs(x.astype(jnp.float32)).max(
-            axis=(0, 1, 3)), 1e-6)                     # (Hkv,)
+        axes = tuple(a for a in range(x.ndim) if a != head_axis)
+        amax = jnp.maximum(jnp.abs(x.astype(jnp.float32)).max(axis=axes),
+                           1e-6)                       # (Hkv,)
         scale = amax / 127.0
+        bshape = [1] * x.ndim
+        bshape[head_axis] = -1
         xi = jnp.clip(jnp.round(x.astype(jnp.float32)
-                                / scale[None, None, :, None]),
+                                / scale.reshape(bshape)),
                       -127, 127).astype(jnp.int8)
         return xi, scale
     ki, ks = one(k)

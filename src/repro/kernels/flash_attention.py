@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.jax_compat import CompilerParams as _CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -109,7 +107,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

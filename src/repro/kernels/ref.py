@@ -45,10 +45,12 @@ def decode_attention_ref(q, k_cache, v_cache, kv_valid, *, scale: float,
 
 
 def gather_pages(pages, page_table):
-    """(n_pages, ps, Hkv, dh) pool + (B, n_pp) table -> dense (B, L, Hkv, dh)."""
+    """Head-major (n_pages, Hkv, ps, dh) pool + (B, n_pp) table -> dense
+    (B, L, Hkv, dh)."""
     B, n_pp = page_table.shape
-    ps, Hkv, dh = pages.shape[1:]
-    return pages[page_table].reshape(B, n_pp * ps, Hkv, dh)
+    Hkv, ps, dh = pages.shape[1:]
+    return (pages[page_table].transpose(0, 1, 3, 2, 4)
+            .reshape(B, n_pp * ps, Hkv, dh))
 
 
 def paged_decode_attention_ref(q, k_pages, v_pages, page_table, seq_lens, *,

@@ -23,8 +23,6 @@ from __future__ import annotations
 import jax
 from jax.sharding import PartitionSpec as P
 
-from repro.jax_compat import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.jax_compat import shard_map as _shard_map
 
 AXIS = "model"                    # the KV-head mesh axis
 
@@ -52,22 +50,23 @@ def sharded_attend(mesh, attend, q, k_pages, v_pages, k_scale, v_scale,
                    extras, *, q_head_axis: int):
     """Run ``attend`` — any per-head paged attention body — head-sharded.
 
-    q partitions on ``q_head_axis``; k_pages/v_pages on axis 2 (pools
-    are (n_pages, page_size, Hkv, dh)); the (Hkv,) scales on their only
-    axis; every array in ``extras`` (page table, lengths, window starts)
-    replicates. ``attend(q, kp, vp, ksc, vsc, *extras)`` runs once per
-    shard on the local head slice and must return a tensor of q's rank
-    with ``q_head_axis`` as its head dim; slices are all-gathered
-    (tiled) back into full head order and returned replicated.
+    q partitions on ``q_head_axis``; k_pages/v_pages on axis 1 (pools
+    are head-major, (n_pages, Hkv, page_size, dh)); the (Hkv,) scales on
+    their only axis; every array in ``extras`` (page table, lengths,
+    window starts) replicates. ``attend(q, kp, vp, ksc, vsc, *extras)``
+    runs once per shard on the local head slice and must return a tensor
+    of q's rank with ``q_head_axis`` as its head dim; slices are
+    all-gathered (tiled) back into full head order and returned
+    replicated.
     """
-    in_specs = (_spec(q.ndim, q_head_axis), _spec(k_pages.ndim, 2),
-                _spec(v_pages.ndim, 2), P(AXIS), P(AXIS))
+    in_specs = (_spec(q.ndim, q_head_axis), _spec(k_pages.ndim, 1),
+                _spec(v_pages.ndim, 1), P(AXIS), P(AXIS))
     in_specs += tuple(_spec(e.ndim) for e in extras)
 
     def body(q_l, kp_l, vp_l, ks_l, vs_l, *ex):
         out = attend(q_l, kp_l, vp_l, ks_l, vs_l, *ex)
         return jax.lax.all_gather(out, AXIS, axis=q_head_axis, tiled=True)
 
-    fn = _shard_map(body, mesh=mesh, in_specs=in_specs,
-                    out_specs=_spec(q.ndim), **{_CHECK_KW: False})
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=_spec(q.ndim), check_vma=False)
     return fn(q, k_pages, v_pages, k_scale, v_scale, *extras)

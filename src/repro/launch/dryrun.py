@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("REPRO_EXTRA_XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run (deliverable e) + roofline extraction (deliverable g).
 
 For every (architecture x input-shape x mesh) cell:
@@ -17,6 +13,7 @@ Usage:
 import argparse
 import gc
 import json
+import os
 import pathlib
 import time
 import traceback
@@ -323,6 +320,10 @@ def main() -> None:
     ap.add_argument("--flash-acc", default="float32")
     ap.add_argument("--cache-dtype", default="")
     args = ap.parse_args()
+    if not os.environ.get("XLA_FLAGS"):
+        # the production meshes need 512 host placeholder devices; the
+        # backend reads the flag when it starts, at the first mesh below
+        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     opts = RuntimeOptions(attn_impl=args.attn_impl, remat=args.remat,
                           block_q=args.block_q, block_kv=args.block_kv,

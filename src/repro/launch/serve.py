@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.configs.reduce import reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import RuntimeOptions
 from repro.serving import ServeEngine
 
@@ -142,6 +143,7 @@ def main() -> None:
                     help="per-request p95 inter-token-latency target for "
                          "the goodput report")
     args = ap.parse_args()
+    use_compile_cache()
     wants_trace = (args.trace_out or args.slo_ttft_ms is not None
                    or args.slo_itl_ms is not None)
     if wants_trace and args.scheduler != "continuous":

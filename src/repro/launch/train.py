@@ -12,6 +12,7 @@ import argparse
 
 from repro.configs import get_config
 from repro.configs.reduce import reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import RuntimeOptions
 from repro.optim import AdamWConfig
 from repro.train import TrainConfig, train
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--dtype", default="float32")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

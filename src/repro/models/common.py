@@ -147,7 +147,7 @@ def attention(q, k, v, *, mask_kind: str = "causal", window: int = 0,
     """GQA attention with lazy masks and flash-style KV blocking.
 
     q: (B,S,H,dh); k/v: (B,L,Hkv,dh); kv_valid: optional (B,) valid length.
-    ``impl='pallas'`` routes to the Pallas flash kernel when eligible.
+    ``impl='pallas'`` runs the Pallas flash kernel (ineligible shapes raise).
     """
     B, S, H, dh = q.shape
     L, Hkv = k.shape[1], k.shape[2]
@@ -155,12 +155,10 @@ def attention(q, k, v, *, mask_kind: str = "causal", window: int = 0,
     scale = scale if scale is not None else 1.0 / math.sqrt(dh)
     if impl == "pallas":
         from repro.kernels import ops as kops
-        out = kops.try_flash_attention(
+        return kops.flash_attention(
             q, k, v, mask_kind=mask_kind, window=window,
             prefix_len=prefix_len, q_offset=q_offset, kv_valid=kv_valid,
             scale=scale, softcap=softcap)
-        if out is not None:
-            return out
     group = H // Hkv
     qg = q.reshape(B, S, Hkv, group, dh)
     qpos = jnp.arange(S) + q_offset

@@ -480,8 +480,12 @@ def paged_supported(cfg: ArchConfig) -> Optional[str]:
 
 def init_paged_cache(cfg: ArchConfig, n_pages: int, page_size: int,
                      opts: RuntimeOptions = RuntimeOptions()):
-    """Pooled KV pages: (n_layers, n_pages, page_size, Hkv, dh) per k/v.
+    """Pooled KV pages: (n_layers, n_pages, Hkv, page_size, dh) per k/v.
 
+    Head-major, so one (page, kv-head) block is a contiguous (page_size,
+    dh) tile: the Pallas kernels' K/V blocks then meet the TPU's
+    (sublane, lane) tiling in bf16 and int8 alike, and a head shard of
+    the pool (DESIGN.md SS16) is a slice of whole tiles.
     ``opts.cache_dtype='int8'`` stores int8 pages with per-(layer, kv-head)
     scales (statically calibrated at the first prefill — the tiered-KV
     policy of DESIGN.md SS3 applied to the page pool)."""
@@ -491,7 +495,7 @@ def init_paged_cache(cfg: ArchConfig, n_pages: int, page_size: int,
     quant = opts.cache_dtype == "int8"
     dtype = (jnp.int8 if quant else
              (jnp.dtype(opts.cache_dtype) if opts.cache_dtype else opts.jdtype))
-    shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
     c = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if quant:
         c["k_scale"] = jnp.ones((cfg.n_layers, cfg.n_kv_heads), jnp.float32)
@@ -538,6 +542,22 @@ def _head_shards(opts: RuntimeOptions, n_kv_heads: int) -> int:
     return ksh.head_shards(opts.kv_shard_mesh, n_kv_heads)
 
 
+def _gather_pages(pages, page_table):
+    """Head-major (n_pages, Hkv, ps, dh) pool rows of each sequence's
+    page table -> dense (B, n_pp * ps, Hkv, dh), for the XLA path."""
+    B, n_pp = page_table.shape
+    Hkv, ps, dh = pages.shape[1:]
+    return (pages[page_table].transpose(0, 1, 3, 2, 4)
+            .reshape(B, n_pp * ps, Hkv, dh))
+
+
+def _scatter_pages(pages, pid, off, rows):
+    """Write ``rows`` (N, Hkv, dh) into head-major pages at (page ``pid``,
+    in-page offset ``off``), both (N,). Duplicate targets (pad rows on the
+    null page) land in unspecified order; nothing reads them."""
+    return pages.at[pid, :, off].set(rows)
+
+
 def _chunk_attend(q, kp, vp, ksc, vsc, page_table, start, n_valid, *,
                   cfg: ArchConfig, opts: RuntimeOptions):
     """Attend a (B, C, H', hd) query chunk over pooled pages.
@@ -547,56 +567,51 @@ def _chunk_attend(q, kp, vp, ksc, vsc, page_table, start, n_valid, *,
     body under ``kernels.sharded.sharded_attend``). ``ksc``/``vsc`` are
     the int8 per-head scales matching kp/vp's head slice, or None."""
     B, C, H, hd = q.shape
-    Hkv, ps = kp.shape[2], kp.shape[1]
+    Hkv, ps = kp.shape[1], kp.shape[2]
     n_pp = page_table.shape[1]
     quant = ksc is not None
-    out = None
-    if opts.attn_impl == "pallas" and not cfg.logit_softcap:
+    if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
         if jnp.ndim(start) == 1:
             # per-sequence window start => speculative-verify entry (SS14)
-            out = kops.try_spec_verify_attention(
+            return kops.spec_verify_attention(
                 q, kp, vp, page_table, start,
                 n_valid - jnp.asarray(start, jnp.int32), scale=hd ** -0.5,
-                k_scale=ksc, v_scale=vsc)
-        else:
-            out = kops.try_chunk_prefill_attention(
-                q, kp, vp, page_table, start, n_valid, scale=hd ** -0.5,
-                k_scale=ksc, v_scale=vsc)
-    if out is None:
-        # XLA path: gather the pages densely, causal-mask by position
-        kd = kp[page_table].reshape(B, n_pp * ps, Hkv, hd)
-        vd = vp[page_table].reshape(B, n_pp * ps, Hkv, hd)
-        if quant:
-            kd = kd.astype(q.dtype) * ksc[None, None, :, None].astype(q.dtype)
-            vd = vd.astype(q.dtype) * vsc[None, None, :, None].astype(q.dtype)
-        else:
-            kd, vd = kd.astype(q.dtype), vd.astype(q.dtype)
-        start_v = jnp.asarray(start, jnp.int32)
-        if start_v.ndim == 0:
-            out = cm.attention(q, kd, vd, mask_kind="causal", q_offset=start,
-                               kv_valid=n_valid, softcap=cfg.logit_softcap,
-                               impl="xla")
-        else:
-            # per-sequence window start (speculative verify, SS14):
-            # cm.attention's q_offset is scalar-only, so build the (B, C, L)
-            # mask explicitly — same numerics as its small path otherwise
-            L = n_pp * ps
-            group = H // Hkv
-            qpos = start_v[:, None] + jnp.arange(C)[None, :]
-            qpos = jnp.minimum(qpos, n_valid[:, None] - 1)   # clip pad rows
-            m = jnp.arange(L)[None, None, :] <= qpos[:, :, None]
-            qg = q.reshape(B, C, Hkv, group, hd)
-            s = jnp.einsum("bshgd,blhd->bshgl", qg, kd,
-                           preferred_element_type=jnp.float32) * (hd ** -0.5)
-            if cfg.logit_softcap:
-                s = jnp.tanh(s / cfg.logit_softcap) * cfg.logit_softcap
-            s = jnp.where(m[:, :, None, None, :], s, cm.NEG_INF)
-            pr = jax.nn.softmax(s, axis=-1)
-            out = jnp.einsum("bshgl,blhd->bshgd", pr.astype(vd.dtype), vd,
-                             preferred_element_type=jnp.float32)
-            out = out.reshape(B, C, H, hd).astype(q.dtype)
-    return out
+                k_scale=ksc, v_scale=vsc, softcap=cfg.logit_softcap)
+        return kops.chunk_prefill_attention(
+            q, kp, vp, page_table, start, n_valid, scale=hd ** -0.5,
+            k_scale=ksc, v_scale=vsc, softcap=cfg.logit_softcap)
+    # XLA path: gather the pages densely, causal-mask by position
+    kd = _gather_pages(kp, page_table)
+    vd = _gather_pages(vp, page_table)
+    if quant:
+        kd = kd.astype(q.dtype) * ksc[None, None, :, None].astype(q.dtype)
+        vd = vd.astype(q.dtype) * vsc[None, None, :, None].astype(q.dtype)
+    else:
+        kd, vd = kd.astype(q.dtype), vd.astype(q.dtype)
+    start_v = jnp.asarray(start, jnp.int32)
+    if start_v.ndim == 0:
+        return cm.attention(q, kd, vd, mask_kind="causal", q_offset=start,
+                            kv_valid=n_valid, softcap=cfg.logit_softcap,
+                            impl="xla")
+    # per-sequence window start (speculative verify, SS14):
+    # cm.attention's q_offset is scalar-only, so build the (B, C, L)
+    # mask explicitly — same numerics as its small path otherwise
+    L = n_pp * ps
+    group = H // Hkv
+    qpos = start_v[:, None] + jnp.arange(C)[None, :]
+    qpos = jnp.minimum(qpos, n_valid[:, None] - 1)   # clip pad rows
+    m = jnp.arange(L)[None, None, :] <= qpos[:, :, None]
+    qg = q.reshape(B, C, Hkv, group, hd)
+    s = jnp.einsum("bshgd,blhd->bshgl", qg, kd,
+                   preferred_element_type=jnp.float32) * (hd ** -0.5)
+    if cfg.logit_softcap:
+        s = jnp.tanh(s / cfg.logit_softcap) * cfg.logit_softcap
+    s = jnp.where(m[:, :, None, None, :], s, cm.NEG_INF)
+    pr = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bshgl,blhd->bshgd", pr.astype(vd.dtype), vd,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, C, H, hd).astype(q.dtype)
 
 
 def prefill_paged(cfg: ArchConfig, params, tokens, cache, page_table,
@@ -615,14 +630,15 @@ def prefill_paged(cfg: ArchConfig, params, tokens, cache, page_table,
     logits, _, (_, kv_stack) = forward(cfg, params, tokens, opts,
                                        collect_kv=True)
     st = cache["stack"]
-    ps = st["k"].shape[2]
+    ps = st["k"].shape[3]
     B, S = tokens.shape
     npp = S // ps
     flat_ids = page_table.reshape(-1)                   # (B * npp,)
 
-    def chunked(val):                                   # (L,B,S,Hkv,dh)
+    def chunked(val):              # (L,B,S,Hkv,dh) -> (L,B*npp,Hkv,ps,dh)
         nl = val.shape[0]
-        return val.reshape(nl, B * npp, ps, *val.shape[3:])
+        return (val.reshape(nl, B * npp, ps, *val.shape[3:])
+                .transpose(0, 1, 3, 2, 4))
 
     if "k_scale" in st:
         if calibrate:
@@ -666,7 +682,7 @@ def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
     k = cm.apply_rope(k, positions)
     quant = "k_scale" in cache_layer
     kp, vp = cache_layer["k"], cache_layer["v"]
-    P, ps = kp.shape[0], kp.shape[1]
+    ps = kp.shape[2]
     n_pp = page_table.shape[1]
 
     if quant:
@@ -690,12 +706,10 @@ def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
     # explicitly — gather would silently clamp to the LAST entry)
     blk = positions // ps
     pid = jnp.take_along_axis(page_table, jnp.minimum(blk, n_pp - 1), axis=1)
-    pid = jnp.where(blk < n_pp, pid, 0)                             # (B, C)
-    flat = (pid * ps + positions % ps).reshape(-1)
-    kp = (kp.reshape(P * ps, Hkv, hd).at[flat]
-          .set(k_store.reshape(B * C, Hkv, hd)).reshape(kp.shape))
-    vp = (vp.reshape(P * ps, Hkv, hd).at[flat]
-          .set(v_store.reshape(B * C, Hkv, hd)).reshape(vp.shape))
+    pid = jnp.where(blk < n_pp, pid, 0).reshape(-1)             # (B * C,)
+    off = (positions % ps).reshape(-1)
+    kp = _scatter_pages(kp, pid, off, k_store.reshape(B * C, Hkv, hd))
+    vp = _scatter_pages(vp, pid, off, v_store.reshape(B * C, Hkv, hd))
 
     n_sh = _head_shards(opts, Hkv)
     if n_sh:
@@ -783,30 +797,23 @@ def _decode_attend(q, kp, vp, ksc, vsc, page_table, valid, *,
 
     Head counts come from the operands (see ``_chunk_attend``) so the
     body runs unchanged on one head shard of the pool."""
-    B, _, H, hd = q.shape
-    Hkv, ps = kp.shape[2], kp.shape[1]
-    n_pp = page_table.shape[1]
-    quant = ksc is not None
-    out = None
-    if opts.attn_impl == "pallas" and not cfg.logit_softcap:
+    hd = q.shape[-1]
+    if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
-        out = kops.try_paged_decode_attention(
+        return kops.paged_decode_attention(
             q[:, 0], kp, vp, page_table, valid, scale=hd ** -0.5,
-            k_scale=ksc, v_scale=vsc)
-        if out is not None:
-            out = out[:, None]                          # (B, 1, H, hd)
-    if out is None:
-        # XLA path: gather the sequence's pages densely, mask by seq_lens
-        kd = kp[page_table].reshape(B, n_pp * ps, Hkv, hd)
-        vd = vp[page_table].reshape(B, n_pp * ps, Hkv, hd)
-        if quant:
-            kd = kd.astype(q.dtype) * ksc[None, None, :, None].astype(q.dtype)
-            vd = vd.astype(q.dtype) * vsc[None, None, :, None].astype(q.dtype)
-        else:
-            kd, vd = kd.astype(q.dtype), vd.astype(q.dtype)
-        out = cm.attention(q, kd, vd, mask_kind="full", kv_valid=valid,
-                           softcap=cfg.logit_softcap, impl="xla")
-    return out
+            k_scale=ksc, v_scale=vsc,
+            softcap=cfg.logit_softcap)[:, None]          # (B, 1, H, hd)
+    # XLA path: gather the sequence's pages densely, mask by seq_lens
+    kd = _gather_pages(kp, page_table)
+    vd = _gather_pages(vp, page_table)
+    if ksc is not None:
+        kd = kd.astype(q.dtype) * ksc[None, None, :, None].astype(q.dtype)
+        vd = vd.astype(q.dtype) * vsc[None, None, :, None].astype(q.dtype)
+    else:
+        kd, vd = kd.astype(q.dtype), vd.astype(q.dtype)
+    return cm.attention(q, kd, vd, mask_kind="full", kv_valid=valid,
+                        softcap=cfg.logit_softcap, impl="xla")
 
 
 def _paged_decode_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
@@ -822,7 +829,7 @@ def _paged_decode_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
     k = cm.apply_rope(k, positions)
     quant = "k_scale" in cache_layer
     kp, vp = cache_layer["k"], cache_layer["v"]
-    P, ps = kp.shape[0], kp.shape[1]
+    ps = kp.shape[2]
 
     if quant:
         ksc, vsc = cache_layer["k_scale"], cache_layer["v_scale"]
@@ -833,12 +840,11 @@ def _paged_decode_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
         k_store, v_store = k[:, 0].astype(kp.dtype), v[:, 0].astype(vp.dtype)
 
     # write the new token's KV at (page_table[b, len//ps], len % ps); the
-    # flat index collapses to the null page for inactive slots (pt == 0)
+    # page id collapses to the null page for inactive slots (pt == 0)
     pid = jnp.take_along_axis(page_table, (seq_lens // ps)[:, None],
                               axis=1)[:, 0]
-    flat = pid * ps + seq_lens % ps                     # (B,)
-    kp = kp.reshape(P * ps, Hkv, hd).at[flat].set(k_store).reshape(kp.shape)
-    vp = vp.reshape(P * ps, Hkv, hd).at[flat].set(v_store).reshape(vp.shape)
+    kp = _scatter_pages(kp, pid, seq_lens % ps, k_store)
+    vp = _scatter_pages(vp, pid, seq_lens % ps, v_store)
     valid = seq_lens + 1
 
     n_sh = _head_shards(opts, Hkv)

@@ -12,9 +12,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.jax_compat import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.jax_compat import shard_map as _shard_map
-
 from repro.configs.base import ArchConfig
 from repro.models import common as cm
 
@@ -189,7 +186,7 @@ def _shard_map_path(p, xf, m, gated: bool, capacity_factor: float, mesh):
         rz = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
         return out, lb, rz
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(ba, None),
                   jax.sharding.PartitionSpec(None, None),
@@ -198,7 +195,7 @@ def _shard_map_path(p, xf, m, gated: bool, capacity_factor: float, mesh):
         out_specs=(jax.sharding.PartitionSpec(ba, None),
                    jax.sharding.PartitionSpec(),
                    jax.sharding.PartitionSpec()),
-        **{_CHECK_KW: False})
+        check_vma=False)
     return fn(xf, p["router"]["w"], p["w_up"], p["w_down"])
 
 

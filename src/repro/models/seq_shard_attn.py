@@ -20,9 +20,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-from repro.jax_compat import SHARD_MAP_CHECK_KW as _CHECK_KW
-from repro.jax_compat import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 NEG_INF = -1e30
@@ -83,9 +80,9 @@ def decode_attn_seq_sharded(q, k_new, v_new, ck, cv, pos, mesh, *,
     ba = tuple(a for a in ("pod", "data") if a in mesh.shape) or None
     rep4 = P(ba, None, None, None)
     cache_spec = P(ba, axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rep4, rep4, rep4, cache_spec, cache_spec, P()),
         out_specs=(rep4, cache_spec, cache_spec),
-        **{_CHECK_KW: False})
+        check_vma=False)
     return fn(q, k_new, v_new, ck, cv, pos)

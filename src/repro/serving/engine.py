@@ -52,6 +52,7 @@ from repro.serving.scheduler import (PREFILLING, RUNNING, AdaptiveSpecK,
                                      ContinuousScheduler, Request)
 from repro.serving.streams import VirtualStream
 from repro.serving.trace import DECODE, DRAFT, STALL, TraceRecorder
+from repro.sharding.rules import auto_mesh
 
 
 def _next_pow2(n: int) -> int:
@@ -222,8 +223,8 @@ class ServeEngine:
                     f"shards={shards} needs {shards} devices but jax sees "
                     f"{ndev}; on CPU export XLA_FLAGS=--xla_force_host_"
                     f"platform_device_count={shards} before importing jax")
-            self.mesh = jax.make_mesh((shards,), ("model",),
-                                      devices=jax.devices()[:shards])
+            self.mesh = auto_mesh((shards,), ("model",),
+                                  devices=jax.devices()[:shards])
             opts = dataclasses.replace(opts, kv_shard_mesh=self.mesh)
         self.shards = shards
         self.overlap = overlap
@@ -280,6 +281,12 @@ class ServeEngine:
         self.decode_lookahead = decode_lookahead
         self.params = params if params is not None else init_params(
             cfg, jax.random.PRNGKey(seed), opts)
+        if self.mesh is not None:
+            # replicate the weights over the mesh once; left on one device
+            # they would be re-copied to every shard on each jitted call
+            from jax.sharding import NamedSharding, PartitionSpec
+            self.params = jax.device_put(
+                self.params, NamedSharding(self.mesh, PartitionSpec()))
         self._prefill = jax.jit(partial(prefill, cfg, opts=opts))
         self._decode = jax.jit(partial(decode_step, cfg, opts=opts),
                                donate_argnums=(3,))
@@ -384,6 +391,7 @@ class ServeEngine:
         self._chunk_shapes: set = set()   # distinct jitted prefill shapes
         self._decode_shapes: set = set()  # distinct jitted decode shapes
         self.kv_manager: Optional[PagedKVManager] = None  # set per serve()
+        self.pool = None          # the last serve's device KV page pool
         # structured trace of the LAST serve_continuous run (SS15), plus
         # its reconcile report (trace audited against ServeStats deltas)
         self.trace: Optional[TraceRecorder] = None
@@ -1048,6 +1056,7 @@ class ServeEngine:
         for tier, n in kv.tier_touches.items():
             self.stats.tier_touches[tier] = (
                 self.stats.tier_touches.get(tier, 0) + n)
+        self.pool = cache
         self.stats.prefill_compiles = len(self._chunk_shapes)
         self.stats.decode_compiles = len(self._decode_shapes)
         assert not sched.waiting and not sched.slots, "unserved requests"

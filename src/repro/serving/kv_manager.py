@@ -59,7 +59,10 @@ DEFAULT_KV_TIERS = KV_TIER_NAMES
 
 
 def page_bytes(cfg: ArchConfig, page_size: int, dtype_bytes: int = 2) -> int:
-    """Bytes one KV page holds across all layers (k + v)."""
+    """Bytes one KV page holds across all layers (k + v): in the
+    head-major pool (``models.init_paged_cache``) that is ``n_layers *
+    n_kv_heads`` tiles of ``(page_size, head_dim)`` per array, and a head
+    shard (DESIGN.md SS16) holds a whole-tile ``1/shards`` of them."""
     per_tok = 2 * cfg.n_kv_heads * cfg.head_dim * cfg.n_layers * dtype_bytes
     return per_tok * page_size
 

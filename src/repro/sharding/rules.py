@@ -19,9 +19,18 @@ import re
 from typing import Dict, Tuple
 
 import jax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
+
+
+def auto_mesh(shape, axes, devices=None) -> Mesh:
+    """A mesh whose axes are all ``Auto``: shardings propagate by GSPMD and
+    ``with_sharding_constraint``, which is how every rule here is written
+    (``jax.make_mesh`` defaults to ``Explicit`` axes, under which those
+    constraints are refused)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_size(mesh: Mesh, name: str) -> int:
@@ -188,19 +197,19 @@ def _len_or_head(mesh, n_heads: int, length: int):
 def paged_cache_pspecs(cache, mesh: Mesh):
     """Shardings for the serve engine's paged KV pool (DESIGN.md SS16).
 
-    The pool k/v arrays are (n_layers, n_pages, page_size, Hkv, head_dim):
-    the KV-head dim shards over "model" when divisible, everything else —
-    including the pages axis, which the replicated page table indexes —
-    replicates. The int8 per-(layer, kv-head) scales stay REPLICATED on
-    purpose: calibration happens outside the shard_map body so every shard
+    The pool k/v arrays are head-major, (n_layers, n_pages, Hkv,
+    page_size, head_dim): the KV-head dim shards over "model" when
+    divisible, everything else — including the pages axis, which the
+    replicated page table indexes — replicates. The int8 per-(layer,
+    kv-head) scales stay REPLICATED on purpose: calibration happens outside the shard_map body so every shard
     quantizes with bitwise-identical scales, and the shard body slices its
     own head block on entry."""
     ms = mesh_axis_size(mesh, "model")
 
     def rule(path, leaf):
         shape = leaf.shape
-        if len(shape) == 5 and shape[3] % ms == 0 and shape[3] >= ms:
-            return P(None, None, None, "model", None)
+        if len(shape) == 5 and shape[2] % ms == 0 and shape[2] >= ms:
+            return P(None, None, "model", None, None)
         return P(*([None] * len(shape)))
     return jax.tree_util.tree_map_with_path(rule, cache)
 

@@ -140,11 +140,11 @@ def test_dryrun_cell_compiles_on_8_devices(tmp_path):
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax
-from jax.sharding import Mesh
 from repro.launch import dryrun
+from repro.launch.mesh import make_host_mesh
 from repro.models import RuntimeOptions
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_host_mesh()
+assert dict(mesh.shape) == {"data": 2, "model": 4}
 with mesh:
     fn, args = dryrun.build_cell("qwen2.5-3b", "decode_32k", mesh,
                                  variant="tp", opts=RuntimeOptions())

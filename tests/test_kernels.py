@@ -140,3 +140,24 @@ def test_decode_attention_valid_masking():
     out2 = da.decode_attention(q, kc2, vc2, valid, interpret=True,
                                block_kv=128)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
+
+
+# ----------------------- kernel entry eligibility ----------------------- #
+
+@pytest.mark.parametrize("dh,ps,dtype,softcap,rule", [
+    (64, 8, jnp.bfloat16, 0.0, "page_size 8"),     # bf16 needs 16 rows
+    (64, 16, jnp.int8, 0.0, "page_size 16"),       # int8 needs 32 rows
+    (16, 16, jnp.float32, 0.0, "head_dim 16"),     # reduced configs
+    (64, 16, jnp.float32, 30.0, "softcap"),
+])
+def test_pallas_entry_refuses_ineligible_shape(dh, ps, dtype, softcap, rule):
+    """With attn_impl='pallas' an ineligible shape raises, naming the rule
+    and the shapes — it never falls back to the XLA path in silence."""
+    from repro.kernels import ops
+    q = jnp.zeros((2, 4, dh), jnp.float32)
+    kp = jnp.zeros((5, 2, ps, dh), dtype)
+    with pytest.raises(ValueError, match=rule) as err:
+        ops.paged_decode_attention(q, kp, kp, jnp.zeros((2, 2), jnp.int32),
+                                   jnp.ones((2,), jnp.int32), scale=1.0,
+                                   softcap=softcap)
+    assert str(tuple(kp.shape)) in str(err.value)

@@ -130,3 +130,26 @@ def test_goodput_summary_counts_blame():
     out = goodput_summary(rep)
     assert out["violator_blame"] == {"stall": 2, "queue": 1}
     assert out["goodput_frac"] == 0.5
+
+
+def test_bench_harness_exits_nonzero_on_module_error(monkeypatch, capsys):
+    """A benchmark module that raises is reported as an ERROR row and the
+    harness exits non-zero after the remaining modules have run."""
+    import types
+
+    from benchmarks import run as harness
+
+    def boom(emit):
+        raise RuntimeError("boom")
+    fake = types.ModuleType("benchmarks.boom")
+    fake.run = boom
+    ok = types.ModuleType("benchmarks.fine")
+    ok.run = lambda emit: "fine"
+    monkeypatch.setitem(sys.modules, "benchmarks.boom", fake)
+    monkeypatch.setitem(sys.modules, "benchmarks.fine", ok)
+    monkeypatch.setattr(harness, "MODULES", ("boom", "fine"))
+    monkeypatch.setattr(sys, "argv", ["run"])
+    assert harness.main() == 1
+    out = capsys.readouterr().out
+    assert "boom.total" in out and "ERROR:RuntimeError:boom" in out
+    assert "fine.total" in out

@@ -18,8 +18,8 @@ from repro.serving import (ContinuousScheduler, PageAllocationError,
 
 def _mk_pages(key, P, ps, Hkv, dh, dtype=jnp.float32):
     ks = jax.random.split(key, 2)
-    kp = jax.random.normal(ks[0], (P, ps, Hkv, dh), jnp.float32).astype(dtype)
-    vp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32).astype(dtype)
+    kp = jax.random.normal(ks[0], (P, Hkv, ps, dh), jnp.float32).astype(dtype)
+    vp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32).astype(dtype)
     return kp, vp
 
 
@@ -64,7 +64,7 @@ def test_paged_kernel_int8():
     kp, vp = _mk_pages(ks[1], P, ps, Hkv, dh)
     pt = _disjoint_tables(ks[2], B, npp, P)
     lens = jnp.array([5, 90], jnp.int32)
-    ki, vi, ksc, vsc = da.quantize_kv(kp, vp)
+    ki, vi, ksc, vsc = da.quantize_kv(kp, vp, head_axis=1)
     out = da.paged_decode_attention(q, ki, vi, pt, lens, k_scale=ksc,
                                     v_scale=vsc, interpret=True)
     want = ref.paged_decode_attention_ref(q, ki, vi, pt, lens,
@@ -92,7 +92,7 @@ def test_paged_kernel_ignores_unowned_pages():
     kp2 = kp.at[mask].set(999.0)
     vp2 = vp.at[mask].set(-999.0)
     # also poison the owned-but-invalid tail of page 5 (rows 13..16)
-    kp2 = kp2.at[5, 5:].set(777.0)
+    kp2 = kp2.at[5, :, 5:].set(777.0)
     out2 = da.paged_decode_attention(q, kp2, vp2, pt, lens, interpret=True)
     np.testing.assert_allclose(out1, out2, atol=1e-6)
 
@@ -284,6 +284,29 @@ def test_continuous_eos_retires_early(small_model):
     # continuous output against the static prefix up to and incl. EOS
     for sa, sb in zip(outs_a, outs_b):
         assert sb == sa[:len(sb)]
+
+
+def test_continuous_pallas_matches_xla():
+    """The served Pallas path (chunk prefill, fused paged decode, prefix
+    cache with copy-on-write) emits the XLA path's greedy tokens. Widths
+    are cut, but head_dim stays 64 so the kernels take the shape they are
+    served at."""
+    cfg = get_config("llama3.2-1b").replace(
+        n_layers=2, d_model=128, n_heads=2, n_kv_heads=1, head_dim=64,
+        d_ff=256, vocab=128)
+    opts = RuntimeOptions(dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0), opts)
+    rng = np.random.default_rng(4)
+    doc = rng.integers(1, cfg.vocab, size=12).tolist()
+    reqs = [doc + rng.integers(1, cfg.vocab, size=n).tolist()
+            for n in (9, 2, 14)]
+    kw = dict(max_len=40, scheduler="continuous", page_size=8, max_batch=2)
+    want = ServeEngine(cfg, params, opts, **kw).serve(reqs, 6)
+    eng = ServeEngine(cfg, params,
+                      RuntimeOptions(dtype="float32", attn_impl="pallas"),
+                      **kw)
+    assert eng.serve(reqs, 6) == want
+    assert eng.stats.cached_prefix_tokens > 0
 
 
 def test_continuous_rejects_unsupported_config():

@@ -34,8 +34,8 @@ def test_chunk_kernel_matches_oracle(B, H, Hkv, dh, ps, C, start, real):
     P = B * npp + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, dh), jnp.float32)
     perm = np.asarray(jax.random.permutation(ks[0], P - 1)) + 1
     pt = jnp.asarray(perm[:B * npp].reshape(B, npp), jnp.int32)
     nv = jnp.full((B,), start + real, jnp.int32)
@@ -53,11 +53,11 @@ def test_chunk_kernel_int8():
     P = npp + 2
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, dh), jnp.float32)
     pt = jnp.asarray([[2, 3, 1]], jnp.int32)
     start, nv = 32, jnp.asarray([48], jnp.int32)
-    ki, vi, ksc, vsc = da.quantize_kv(kp, vp)
+    ki, vi, ksc, vsc = da.quantize_kv(kp, vp, head_axis=1)
     out = da.chunk_prefill_attention(q, ki, vi, pt, start, nv, k_scale=ksc,
                                      v_scale=vsc, interpret=True)
     want = ref.chunk_prefill_attention_ref(q, ki, vi, pt, start, nv,

@@ -36,8 +36,8 @@ def test_spec_verify_kernel_matches_oracle(B, H, Hkv, dh, ps, C, lens, fed):
     P = B * npp + 1
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, dh), jnp.float32)
     perm = np.asarray(jax.random.permutation(ks[0], P - 1)) + 1
     pt = jnp.asarray(perm[:B * npp].reshape(B, npp), jnp.int32)
     sl = jnp.asarray(lens, jnp.int32)
@@ -55,11 +55,11 @@ def test_spec_verify_kernel_int8():
     P = npp + 2
     ks = jax.random.split(jax.random.PRNGKey(1), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, dh), jnp.float32)
     pt = jnp.asarray([[2, 3, 1]], jnp.int32)
     sl, nf = jnp.asarray([40], jnp.int32), jnp.asarray([8], jnp.int32)
-    ki, vi, ksc, vsc = da.quantize_kv(kp, vp)
+    ki, vi, ksc, vsc = da.quantize_kv(kp, vp, head_axis=1)
     out = da.spec_verify_attention(q, ki, vi, pt, sl, nf, k_scale=ksc,
                                    v_scale=vsc, interpret=True)
     want = ref.spec_verify_attention_ref(q, ki, vi, pt, sl, nf,
@@ -81,16 +81,16 @@ def test_spec_verify_rows_ignore_later_draft_kv():
     P = npp + 1
     ks = jax.random.split(jax.random.PRNGKey(2), 3)
     q = jax.random.normal(ks[0], (B, C, H, dh), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, dh), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, dh), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, Hkv, ps, dh), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, Hkv, ps, dh), jnp.float32)
     pt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
     sl = jnp.asarray([lens], jnp.int32)
     nf = jnp.asarray([fed], jnp.int32)
     base = da.spec_verify_attention(q, kp, vp, pt, sl, nf, interpret=True)
     # corrupt the LAST fed position's KV (token index lens+fed-1 = 9,
     # page 2 slot 1) — only the final row may see it
-    kp2 = kp.at[3, 1].set(100.0)
-    vp2 = vp.at[3, 1].set(-100.0)
+    kp2 = kp.at[3, :, 1].set(100.0)
+    vp2 = vp.at[3, :, 1].set(-100.0)
     out = da.spec_verify_attention(q, kp2, vp2, pt, sl, nf, interpret=True)
     np.testing.assert_allclose(out[:, :fed - 1], base[:, :fed - 1],
                                atol=1e-5, rtol=1e-5)

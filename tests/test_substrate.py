@@ -155,3 +155,23 @@ def test_tiered_kv_int8_close_to_native():
     agree = np.mean([a == b for ra, rb in zip(o_native, o_int8)
                      for a, b in zip(ra, rb)])
     assert agree >= 0.75, f"int8 KV diverged: agreement {agree}"
+
+
+# --------------------------- compile cache ----------------------------- #
+
+def test_compile_cache_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to the fixed <repo>/.jax_cache."""
+    from repro.launch import compile_cache as cc
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", None)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert cc.use_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+        assert cc.use_compile_cache() == str(want)
+        assert jax.config.jax_compilation_cache_dir == str(want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -1,0 +1,103 @@
+"""Ahead-of-time compiles of the served Pallas kernels for a TPU v5e.
+
+Interpret mode (every other kernel test) cannot see Mosaic's tiling and
+memory-space rules; the TPU compiler, which compiles for a described chip
+without one attached, can. Each case lowers one kernel at llama3.2-1b
+widths (32 query heads of 64; 8 KV heads as in the released model, or 32
+as in ``configs/llama32_1b.py``) for one chip of a described ``v5e:2x2``
+topology and checks that the kernel survived as a ``tpu_custom_call``.
+Nothing runs, so these say nothing about results or times.
+
+The topology is described only inside the module fixture: the TPU library
+admits one process at a time, and a description made at import time
+would make pytest workers collect different tests.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+import repro.kernels.decode_attention as da
+import repro.kernels.flash_attention as fa
+
+H, HKV, DH = 32, 8, 64          # llama3.2-1b attention widths (GQA)
+B = 8                           # decode slots
+MAX_LEN = 1024
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without the chip; keep it out of the cache
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _pool(page_size, dtype, n_seqs, n_kv_heads=HKV):
+    n_pages = n_seqs * (MAX_LEN // page_size) + 1
+    return ((n_pages, n_kv_heads, page_size, DH), dtype)
+
+
+@pytest.mark.parametrize("kv_dtype,page_size,n_kv_heads", [
+    (jnp.bfloat16, 16, HKV), (jnp.int8, 32, HKV), (jnp.bfloat16, 16, H)])
+def test_paged_decode_compiles(one_chip, kv_dtype, page_size, n_kv_heads):
+    npp = MAX_LEN // page_size
+    quant = kv_dtype == jnp.int8
+
+    def fn(q, kp, vp, pt, lens, ksc, vsc):
+        return da.paged_decode_attention(
+            q, kp, vp, pt, lens, k_scale=ksc if quant else None,
+            v_scale=vsc if quant else None)
+    text = _compile_text(
+        fn, one_chip, ((B, H, DH), jnp.bfloat16),
+        _pool(page_size, kv_dtype, B, n_kv_heads),
+        _pool(page_size, kv_dtype, B, n_kv_heads),
+        ((B, npp), jnp.int32), ((B,), jnp.int32),
+        ((n_kv_heads,), jnp.float32), ((n_kv_heads,), jnp.float32))
+    assert "tpu_custom_call" in text
+
+
+def test_chunk_prefill_compiles(one_chip):
+    C, page_size = 32, 16
+    npp = MAX_LEN // page_size
+    text = _compile_text(
+        da.chunk_prefill_attention, one_chip, ((1, C, H, DH), jnp.bfloat16),
+        _pool(page_size, jnp.bfloat16, 1), _pool(page_size, jnp.bfloat16, 1),
+        ((1, npp), jnp.int32), ((), jnp.int32), ((1,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_spec_verify_compiles(one_chip):
+    C, page_size = 5, 16        # K = 4 drafts + the last committed token
+    npp = MAX_LEN // page_size
+    text = _compile_text(
+        da.spec_verify_attention, one_chip, ((B, C, H, DH), jnp.bfloat16),
+        _pool(page_size, jnp.bfloat16, B), _pool(page_size, jnp.bfloat16, B),
+        ((B, npp), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_flash_attention_compiles(one_chip):
+    S = 1024
+    text = _compile_text(
+        fa.flash_attention, one_chip, ((1, S, H, DH), jnp.bfloat16),
+        ((1, S, HKV, DH), jnp.bfloat16), ((1, S, HKV, DH), jnp.bfloat16))
+    assert "tpu_custom_call" in text
