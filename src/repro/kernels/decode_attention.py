@@ -149,6 +149,7 @@ def decode_attention(q, k_cache, v_cache, kv_valid, *, scale: float = None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attention",
     )(kv_valid.astype(jnp.int32), k_scale.astype(jnp.float32),
       v_scale.astype(jnp.float32), qt, kt, vt)
     return out.reshape(B, H, dh)
@@ -239,6 +240,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, seq_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(page_table.astype(jnp.int32), seq_lens.astype(jnp.int32),
       k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
       qt, k_pages, v_pages)
@@ -347,6 +349,7 @@ def chunk_prefill_attention(q, k_pages, v_pages, page_table, start, n_valid,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="chunk_prefill_attention",
     )(page_table.astype(jnp.int32), start, n_valid.astype(jnp.int32),
       k_scale.astype(jnp.float32), v_scale.astype(jnp.float32),
       qt, k_pages, v_pages)

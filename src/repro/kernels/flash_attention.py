@@ -111,5 +111,6 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float = None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)                   # (B, S, H, dh)

@@ -22,6 +22,7 @@ from repro.configs.reduce import reduced
 from repro.launch.compile_cache import use_compile_cache
 from repro.models import RuntimeOptions
 from repro.serving import ServeEngine
+from repro.serving.trace import HOST_PHASES, LAYER_SPANS
 
 
 def main() -> None:
@@ -267,6 +268,18 @@ def main() -> None:
                 f"{p}={agg[f'{p}_ms']:.1f}ms"
                 for p in ("queue", "prefill", "recompute", "decode",
                           "stall", "draft")))
+            # the same serve on the host's wall clock
+            tr = eng.trace
+            print("[serve] host phases: " + " ".join(
+                f"{p}={tr.host_s[p]*1e3:.1f}ms/{tr.host_n[p]}"
+                for p in HOST_PHASES + LAYER_SPANS if p in tr.host_s))
+            print("[serve] compiled during serve: " + (" ".join(
+                f"{k}={n}" for k, n in sorted(tr.compiles.items()))
+                or "none"))
+            w = tr.wall_summary_ms()
+            print(f"[serve] wall ttft_p50/p90={w['ttft_p50_ms']:.1f}/"
+                  f"{w['ttft_p90_ms']:.1f}ms queue_p50/p90="
+                  f"{w['queue_p50_ms']:.1f}/{w['queue_p90_ms']:.1f}ms")
             if args.slo_ttft_ms is not None or args.slo_itl_ms is not None:
                 rep = eng.trace.slo_report(
                     None if args.slo_ttft_ms is None

@@ -63,6 +63,16 @@ def _next_pow2(n: int) -> int:
     return p
 
 
+def _program(fn, *args, **kwargs):
+    """``partial(fn, ...)`` under ``fn``'s name: jitted, it lowers to
+    module ``jit_<fn>`` and runs under that name in a device trace (a
+    bare partial runs as ``jit__unknown``). Only the name is copied, so
+    JAX still reads the partial's own signature."""
+    p = partial(fn, *args, **kwargs)
+    p.__name__ = p.__qualname__ = fn.__name__
+    return p
+
+
 def _pad_pow2(items: List, pad_item) -> List:
     """Pad a work list to the next power-of-two length with inert filler so
     jitted consumers see O(log n) distinct shapes instead of one compile
@@ -287,14 +297,14 @@ class ServeEngine:
             from jax.sharding import NamedSharding, PartitionSpec
             self.params = jax.device_put(
                 self.params, NamedSharding(self.mesh, PartitionSpec()))
-        self._prefill = jax.jit(partial(prefill, cfg, opts=opts))
-        self._decode = jax.jit(partial(decode_step, cfg, opts=opts),
+        self._prefill = jax.jit(_program(prefill, cfg, opts=opts))
+        self._decode = jax.jit(_program(decode_step, cfg, opts=opts),
                                donate_argnums=(3,))
         # fused K-step greedy decode over the dense cache (static engine).
         # temperature/top_k/top_p are compile-time sampling config — the
         # body branches on them on the host, so they must be static (a
         # traced temperature would hit a concretization error)
-        self._decode_block = jax.jit(partial(decode_steps, cfg, opts=opts),
+        self._decode_block = jax.jit(_program(decode_steps, cfg, opts=opts),
                                      static_argnames=("n_steps",
                                                       "temperature",
                                                       "top_k", "top_p"),
@@ -359,34 +369,34 @@ class ServeEngine:
         self.n_pages = (n_pages if n_pages is not None
                         else max_batch * self.n_pages_per_seq + 1)
         self._prefill_chunk = jax.jit(
-            partial(prefill_paged_chunk, cfg, opts=opts),
+            _program(prefill_paged_chunk, cfg, opts=opts),
             static_argnames=("calibrate",), donate_argnums=(2,))
         # fused K-step decode over the paged pool: sample + EOS-latch on
         # device, one host sync per (B, K) token block (DESIGN.md SS12)
         self._decode_fused = jax.jit(
-            partial(decode_steps_paged, cfg, opts=opts, eos_id=eos_id,
-                    temperature=temperature, top_k=top_k, top_p=top_p),
+            _program(decode_steps_paged, cfg, opts=opts, eos_id=eos_id,
+                     temperature=temperature, top_k=top_k, top_p=top_p),
             static_argnames=("n_steps",), donate_argnums=(4,))
         # speculative verify: one paged multi-query pass scores the whole
         # draft window, leftover/rejection sampling accepts on device (SS14)
         self._spec_verify = jax.jit(
-            partial(spec_decode_verify, cfg, opts=opts,
-                    temperature=temperature, top_k=top_k, top_p=top_p),
+            _program(spec_decode_verify, cfg, opts=opts,
+                     temperature=temperature, top_k=top_k, top_p=top_p),
             donate_argnums=(5,))
         # per-request sampling keys: fold (rid, tokens-emitted) into the
         # serve seed, so a request's randomness is independent of batch
         # composition and survives recompute preemption bit-for-bit
         _base = jax.random.PRNGKey(sample_seed)
 
-        def _bk(rids, emitted):
+        def block_keys(rids, emitted):
             def one(r, e):
                 return jax.random.fold_in(jax.random.fold_in(_base, r), e)
             return jax.vmap(one)(rids, emitted)
-        self._block_keys = jax.jit(_bk)
-        self._sample1 = jax.jit(partial(sampling.sample,
-                                        temperature=temperature,
-                                        top_k=top_k, top_p=top_p))
-        self._copy_pages = jax.jit(partial(copy_pages, cfg),
+        self._block_keys = jax.jit(block_keys)
+        self._sample1 = jax.jit(_program(sampling.sample,
+                                         temperature=temperature,
+                                         top_k=top_k, top_p=top_p))
+        self._copy_pages = jax.jit(_program(copy_pages, cfg),
                                    donate_argnums=(0,))
         self._chunk_shapes: set = set()   # distinct jitted prefill shapes
         self._decode_shapes: set = set()  # distinct jitted decode shapes
@@ -523,6 +533,28 @@ class ServeEngine:
         reserved ahead all-or-nothing, one host sync per block; SS12).
         Prompts sharing an already-seen prefix skip both the recompute and
         the pages (refcounted reuse; COW on mid-page divergence)."""
+        for i, r in enumerate(requests):
+            total = len(r) + max_new_tokens
+            if total > self.max_len:
+                raise ValueError(f"request {i}: prompt({len(r)}) + "
+                                 f"new({max_new_tokens}) exceeds "
+                                 f"max_len={self.max_len}")
+        # structured trace (SS15): one recorder per serve, threaded through
+        # the scheduler / KV manager / tier device / drafter; ServeStats is
+        # audited against it when the run finishes (reconcile). Host
+        # phases tile the serve on the wall clock from here to reconcile.
+        trace = TraceRecorder()
+        self.trace = trace
+        trace.phase("setup")
+        try:
+            return self._serve_paged(trace, requests, max_new_tokens)
+        finally:
+            trace.end_phases()   # after a failure: closes the open phase
+                                 # and drops the compile listener
+
+    def _serve_paged(self, trace: TraceRecorder, requests: List[List[int]],
+                     max_new_tokens: int) -> List[List[int]]:
+        """The body of ``serve_continuous``, recording into ``trace``."""
         ps, n_pp = self.page_size, self.n_pages_per_seq
         B = self.max_batch
         C = self.prefill_chunk
@@ -554,11 +586,6 @@ class ServeEngine:
         def now() -> float:
             return max(sched_t[0], min(pstream.free, dstream.free))
 
-        # structured trace (SS15): one recorder per serve, threaded through
-        # the scheduler / KV manager / tier device / drafter; ServeStats is
-        # audited against it when the run finishes (reconcile below)
-        trace = TraceRecorder()
-        self.trace = trace
         # stats accumulate across serve() calls but the trace covers only
         # this one — snapshot now, reconcile against the deltas
         snap_stall = self.stats.stall_s
@@ -628,7 +655,8 @@ class ServeEngine:
             swaps/spills and charge write-back now, defer the demand-fetch
             issue until the kernel's wall time is known so the fetch can
             be layer-sliced against the layer loop."""
-            return kv.plan_residency([r.rid for r in reqs], t0)
+            with trace.layer("kv.residency"):
+                return kv.plan_residency([r.rid for r in reqs], t0)
 
         def stall_charge(plan, reqs: List[Request], t0: float, dw: float,
                          track: str) -> float:
@@ -639,9 +667,10 @@ class ServeEngine:
             duration), each request is charged its OWN pages' wait scaled
             by the overlap savings (SS13/SS17)."""
             per: Dict[int, float] = {}
-            s, barrier = kv.charge_residency(
-                plan, t0, n_slices=self.n_layer_slices, compute_s=dw,
-                per_seq=per)
+            with trace.layer("kv.residency"):
+                s, barrier = kv.charge_residency(
+                    plan, t0, n_slices=self.n_layer_slices, compute_s=dw,
+                    per_seq=per)
             if s > 0:
                 self.stats.stall_s += s
             self.stats.stall_saved_s += max(0.0, barrier - s)
@@ -656,11 +685,6 @@ class ServeEngine:
             return s
 
         for i, r in enumerate(requests):
-            total = len(r) + max_new_tokens
-            if total > self.max_len:
-                raise ValueError(f"request {i}: prompt({len(r)}) + "
-                                 f"new({max_new_tokens}) exceeds "
-                                 f"max_len={self.max_len}")
             req = Request(rid=i, prompt=list(r),
                           max_new_tokens=max_new_tokens)
             req.t_submit = now()
@@ -698,17 +722,20 @@ class ServeEngine:
 
         def apply_copies():
             nonlocal cache
-            pairs = kv.drain_copies()
-            if pairs:
-                # pad to a power-of-two batch with null-page self-copies so
-                # the jitted scatter sees O(log) distinct shapes, not one
-                # compile per COW-batch size
-                pairs = _pad_pow2(pairs, (0, 0))
-                cache = self._copy_pages(cache,
-                                         jnp.asarray(pairs, jnp.int32))
+            with trace.layer("kv.copies"):
+                pairs = kv.drain_copies()
+                if pairs:
+                    # pad to a power-of-two batch with null-page self-copies
+                    # so the jitted scatter sees O(log) distinct shapes, not
+                    # one compile per COW-batch size
+                    pairs = _pad_pow2(pairs, (0, 0))
+                    cache = self._copy_pages(cache,
+                                             jnp.asarray(pairs, jnp.int32))
 
         while sched.has_work:
-            admitted = sched.admit()
+            trace.phase("admit")
+            with trace.layer("sched.admit"):
+                admitted = sched.admit()
             if admitted:
                 # start migrating any offload-resident cached-prefix pages
                 # toward the fast tiers before their first prefill chunk
@@ -723,6 +750,7 @@ class ServeEngine:
                 pf = req.prefill_tokens
                 F = len(pf)
                 while budget >= C and req.state == PREFILLING:
+                    trace.phase("prefill.prep")
                     start = req.n_prefilled
                     n_real = min(C, F - start)
                     toks = np.zeros((1, C), np.int32)
@@ -735,23 +763,25 @@ class ServeEngine:
                     # against the chunk's layer loop after the kernel's
                     # wall time is measured (SS17)
                     plan = stall_plan([req], t0)
-                    w0 = time.perf_counter()
+                    args = (jnp.asarray(toks), cache, jnp.asarray(pt),
+                            jnp.int32(start),
+                            jnp.asarray([start + n_real], jnp.int32))
+                    trace.phase("prefill.run", rid=req.rid, start=start,
+                                n=n_real)
                     logits, cache = self._prefill_chunk(
-                        self.params, jnp.asarray(toks), cache,
-                        jnp.asarray(pt), jnp.int32(start),
-                        jnp.asarray([start + n_real], jnp.int32),
-                        calibrate=not calibrated)
+                        self.params, *args, calibrate=not calibrated)
                     logits.block_until_ready()
-                    dw = time.perf_counter() - w0
-                    s = stall_charge(plan, [req], t0, dw, "prefill")
                     self.stats.host_syncs += 1
-                    calibrated = True
+                    dw = trace.phase_elapsed()
+                    s = stall_charge(plan, [req], t0, dw, "prefill")
                     t1 = pstream.commit(t0, s + dw)
-                    self.stats.prefill_s += t1 - t0
                     trace.engine_span(
                         "prefill_chunk", t0, t1,
                         {"rid": req.rid, "tokens": [start, start + n_real]},
                         track="prefill")
+                    trace.phase("prefill.commit")
+                    calibrated = True
+                    self.stats.prefill_s += t1 - t0
                     # recompute/prefill split by the request's computed
                     # high-water mark (re-prefill after preemption)
                     trace.prefill_span(req.rid, t0, t1, start,
@@ -767,6 +797,7 @@ class ServeEngine:
                     if req.n_prefilled >= F:
                         sched.finish_prefill(slot)
                         decode_ready[req.rid] = t1   # decodable from t1
+                        trace.phase("first_token")
                         if self.temperature > 0:
                             # first token of the request: sampled from the
                             # (rid, 0) key so it is schedule-independent
@@ -814,16 +845,17 @@ class ServeEngine:
                 # ==== speculative decode block (DESIGN.md SS14) ==== #
                 # draft proposes up to k tokens per request; ONE verify
                 # pass streams weights+KV once and lands n_acc+1 tokens
+                trace.phase("spec.propose")
                 items = [(req, min(adaptive.k_for(req), req.remaining - 1))
                          for _, req in parts]
-                w0 = time.perf_counter()
                 props = draft.propose_all(items)
                 # a model draft pulls its proposed block to the host; the
                 # n-gram draft is host-only and reports zero
                 self.stats.host_syncs += draft.take_host_syncs()
-                td = dstream.commit(t0, time.perf_counter() - w0)
+                td = dstream.commit(t0, trace.phase_elapsed())
                 trace.engine_span("spec_propose", t0, td,
                                   {"n_seqs": len(items)}, track="decode")
+                trace.phase("decode.reserve")
                 for _, r in parts:
                     # the whole batch waits out the proposal pass
                     trace.span(r.rid, DRAFT, t0, td)
@@ -831,10 +863,11 @@ class ServeEngine:
                 # LIFO preemption may evict ANY slot — diff the full table
                 before = dict(sched.slots)
                 sched_t[0] = td       # evictions stamp at reservation time
-                for slot, req in parts:
-                    if slot in sched.slots:
-                        sched.reserve_lookahead(
-                            slot, len(props.get(req.rid, ())) + 1)
+                with trace.layer("sched.reserve"):
+                    for slot, req in parts:
+                        if slot in sched.slots:
+                            sched.reserve_lookahead(
+                                slot, len(props.get(req.rid, ())) + 1)
                 sched_t[0] = 0.0
                 evicted = [r for s, r in before.items()
                            if s not in sched.slots]
@@ -847,6 +880,7 @@ class ServeEngine:
                 note_peak()
                 if not parts:
                     continue
+                trace.phase("decode.prep")
                 # clamp the verify window to the largest live draft,
                 # rounded up to a power of two (O(log K) compiled shapes)
                 max_dl = max(len(props.get(r.rid, ())) for _, r in parts)
@@ -872,14 +906,15 @@ class ServeEngine:
                 self._decode_shapes.add(("spec", B, n_tok))
                 tb = dstream.start()
                 plan = stall_plan([r for _, r in parts], tb)
-                w0 = time.perf_counter()
-                out, n_acc, _, cache = self._spec_verify(
-                    self.params, jnp.asarray(tokens),
-                    jnp.asarray(draft_len), jnp.asarray(seq_lens),
-                    jnp.asarray(tables), cache, keys)
+                args = (jnp.asarray(tokens), jnp.asarray(draft_len),
+                        jnp.asarray(seq_lens), jnp.asarray(tables), cache,
+                        keys)
+                trace.phase("spec.run", n_tok=n_tok, n_seqs=len(parts))
+                out, n_acc, _, cache = self._spec_verify(self.params, *args)
                 out_np = np.asarray(out)
                 nacc_np = np.asarray(n_acc)
-                dw = time.perf_counter() - w0
+                self.stats.host_syncs += 1
+                dw = trace.phase_elapsed()
                 s = stall_charge(plan, [r for _, r in parts], tb, dw,
                                  "decode")
                 tv = dstream.commit(tb, s + dw)
@@ -887,7 +922,7 @@ class ServeEngine:
                 trace.engine_span("spec_verify", tb, tv,
                                   {"n_tok": n_tok, "n_seqs": len(parts)},
                                   track="decode")
-                self.stats.host_syncs += 1
+                trace.phase("decode.emit")
                 self.stats.decode_s += dt
                 self.stats.decode_steps += 1    # one streaming pass
                 self.stats.spec_blocks += 1
@@ -928,12 +963,15 @@ class ServeEngine:
                 # preempt): K lookahead writes per slot, all-or-nothing;
                 # LIFO preemption may evict ANY slot, including a
                 # just-admitted PREFILLING one — diff the full slot table
+                trace.phase("decode.reserve")
                 K = self.decode_lookahead
                 before = dict(sched.slots)
                 sched_t[0] = t0       # evictions stamp at the block start
-                for slot, req in parts:
-                    if slot in sched.slots:     # may have been preempted
-                        sched.reserve_lookahead(slot, min(K, req.remaining))
+                with trace.layer("sched.reserve"):
+                    for slot, req in parts:
+                        if slot in sched.slots:  # may have been preempted
+                            sched.reserve_lookahead(slot,
+                                                    min(K, req.remaining))
                 sched_t[0] = 0.0
                 evicted = [r for s, r in before.items()
                            if s not in sched.slots]
@@ -947,6 +985,7 @@ class ServeEngine:
                 if not parts:
                     continue
 
+                trace.phase("decode.prep")
                 # ---- one fused K-step decode block over the ready slots:
                 # sampling, EOS latching, and length advance happen on
                 # device; one host sync per (B, K) block (DESIGN.md SS12)
@@ -972,28 +1011,25 @@ class ServeEngine:
                 # outruns its prefetch absorbs the residual as recorded
                 # stall, shrunk by the layer-loop overlap
                 plan = stall_plan([r for _, r in parts], t0)
-                w0 = time.perf_counter()
+                args = (jnp.asarray(tokens), jnp.asarray(seq_lens),
+                        jnp.asarray(tables), cache)
+                kw = dict(done=jnp.asarray(inactive),
+                          quota=jnp.asarray(quota))
                 if self.temperature > 0:
                     rids = np.zeros((B,), np.int32)
                     emitted = np.zeros((B,), np.int32)
                     for slot, req in parts:
                         rids[slot] = req.rid
                         emitted[slot] = len(req.out)
-                    keys = self._block_keys(jnp.asarray(rids),
-                                            jnp.asarray(emitted))
-                    blk, cache, _ = self._decode_fused(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(seq_lens), jnp.asarray(tables), cache,
-                        n_steps=n_steps, keys=keys,
-                        done=jnp.asarray(inactive), quota=jnp.asarray(quota))
-                else:
-                    blk, cache = self._decode_fused(
-                        self.params, jnp.asarray(tokens),
-                        jnp.asarray(seq_lens), jnp.asarray(tables), cache,
-                        n_steps=n_steps, done=jnp.asarray(inactive),
-                        quota=jnp.asarray(quota))
+                    kw["keys"] = self._block_keys(jnp.asarray(rids),
+                                                  jnp.asarray(emitted))
+                trace.phase("decode.run", n_steps=n_steps,
+                            n_seqs=len(parts))
+                blk, cache, *_ = self._decode_fused(
+                    self.params, *args, n_steps=n_steps, **kw)
                 blk_np = np.asarray(blk)
-                dw = time.perf_counter() - w0
+                self.stats.host_syncs += 1
+                dw = trace.phase_elapsed()
                 s = stall_charge(plan, [r for _, r in parts], t0, dw,
                                  "decode")
                 tv = dstream.commit(t0, s + dw)
@@ -1001,7 +1037,7 @@ class ServeEngine:
                 trace.engine_span("decode_block", t0, tv,
                                   {"n_steps": n_steps,
                                    "n_seqs": len(parts)}, track="decode")
-                self.stats.host_syncs += 1
+                trace.phase("decode.emit")
                 self.stats.decode_s += dt
                 self.stats.decode_steps += n_steps
 
@@ -1037,6 +1073,7 @@ class ServeEngine:
                 kv.prefetch_seqs(cont, t0, lookahead_seqs=[
                     r.rid for _, r in sched.prefilling()])
 
+        trace.phase("finish")
         self.stats.requests += len(requests)
         self.stats.cached_prefix_tokens += kv.dedup_tokens
         self.stats.pages_deduped += kv.dedup_hits
@@ -1066,7 +1103,8 @@ class ServeEngine:
         self.stats.serve_s += max(pstream.free, dstream.free)
         # close the trace and audit the aggregate counters against it:
         # phase sums == e2e per request, stall totals and samples match
-        # this serve's ServeStats deltas (raises on drift — SS15)
+        # this serve's ServeStats deltas, host phases tile the wall time
+        # (raises on drift — SS15)
         trace.finalize(max(pstream.free, dstream.free))
         self.trace_report = trace.reconcile(
             stall_s=self.stats.stall_s - snap_stall,

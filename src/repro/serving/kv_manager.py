@@ -51,6 +51,7 @@ import numpy as np
 
 from repro.configs.base import ArchConfig
 from repro.serving.channels import KV_TIER_NAMES, make_label
+from repro.serving.trace import layer_span
 
 # tiers a KV page may occupy, preferred (fastest) first; mirrors the
 # placement policies in repro.core.placement and the channel vocabulary
@@ -1211,29 +1212,30 @@ class PagedKVManager:
         Returns the number of newly indexed pages."""
         if not self.enable_prefix_cache:
             return 0
-        s = self._seqs[seq_id]
-        limit = min(len(tokens), s.n_tokens,
-                    n_valid if n_valid is not None else s.n_tokens)
-        ps = self.page_size
-        parent = b""
-        added = 0
-        for b in range(limit // ps):
-            block = tuple(tokens[b * ps:(b + 1) * ps])
-            key = _chain_digest(parent, block)
-            if key not in self._index:
-                page = s.pages[b]
-                if page in self._page_key:
-                    # page already indexed under another chain (e.g. the
-                    # request itself reused it) — leave that entry alone
-                    parent = key
-                    continue
-                self._index[key] = page
-                self._page_key[page] = key
-                self._children.setdefault(parent, {})[key] = page
-                self._parent_key[key] = parent
-                self._block_tokens[key] = block
-                added += 1
-            parent = key
+        with layer_span(self.tracer, "kv.register_prefix"):
+            s = self._seqs[seq_id]
+            limit = min(len(tokens), s.n_tokens,
+                        n_valid if n_valid is not None else s.n_tokens)
+            ps = self.page_size
+            parent = b""
+            added = 0
+            for b in range(limit // ps):
+                block = tuple(tokens[b * ps:(b + 1) * ps])
+                key = _chain_digest(parent, block)
+                if key not in self._index:
+                    page = s.pages[b]
+                    if page in self._page_key:
+                        # page already indexed under another chain (e.g.
+                        # the request itself reused it) — leave it alone
+                        parent = key
+                        continue
+                    self._index[key] = page
+                    self._page_key[page] = key
+                    self._children.setdefault(parent, {})[key] = page
+                    self._parent_key[key] = parent
+                    self._block_tokens[key] = block
+                    added += 1
+                parent = key
         return added
 
     def lookup_prefix(self, tokens: Sequence[int]) -> int:

@@ -25,12 +25,34 @@ total stall, per-request stall attribution, the TTFT/ITL sample sets and
 the emitted-token count must all match the events within float
 tolerance, so the aggregate counters can no longer silently drift from
 what actually happened.
+
+Beside the virtual clock the recorder keeps the host's wall clock
+(``time.perf_counter``), which the exports above never read:
+
+* **Host phases** (``phase``) tile a serve from entry to ``reconcile``:
+  exactly one of ``HOST_PHASES`` is open at a time, each is a profiler
+  annotation ``engine.<phase>`` (so it lands on the JAX profiler's host
+  plane, on the device ops' clock), and its seconds and count add up in
+  ``host_s`` / ``host_n``. ``reconcile`` checks that they sum to the
+  serve's wall time.
+* **Layer spans** (``layer``) nest inside a phase: the scheduler's
+  admission and lookahead reservation, the KV manager's prefix index,
+  page copies and residency (``LAYER_SPANS``), also in ``host_s``.
+* **Compiles**: a ``jax.monitoring`` listener, live while phases run,
+  counts backend compiles in ``compiles`` by the program compiled; a
+  phase during which one happened carries ``compiled=<n>``.
+* **Request stamps** (``wall``): submit, first admission and every
+  token of each request.
 """
 from __future__ import annotations
 
 import json
+import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
+
+import jax
 
 from repro.serving import metrics
 
@@ -43,6 +65,28 @@ DECODE = "decode"        # fused decode blocks / spec verify passes
 STALL = "stall"          # fetch-wait on THIS request's offload pages
 DRAFT = "draft"          # speculative draft proposal overhead
 PHASES = (QUEUE, PREFILL, RECOMPUTE, DECODE, STALL, DRAFT)
+
+# ---- host phases of the engine loop (wall clock, one open at a time) ---- #
+HOST_PHASES = (
+    "setup",           # pool, KV manager, scheduler, request submission
+    "admit",           # admission, prefix prefetch, COW copies
+    "prefill.prep",    # chunk token and page-table arrays, uploads, plan
+    "prefill.run",     # chunk dispatch through block_until_ready
+    "prefill.commit",  # chunk bookkeeping, prefix index
+    "first_token",     # first-token pull, emit, retire
+    "decode.reserve",  # lookahead reservation (may preempt), COW copies
+    "decode.prep",     # block arrays, sampling keys, uploads, plan
+    "decode.run",      # fused block dispatch through its host pull
+    "decode.emit",     # token distribution, commit, retire, prefetch
+    "spec.propose",    # draft proposal
+    "spec.run",        # verify pass dispatch through its host pull
+    "finish",          # counters folded into ServeStats, audit
+)
+# spans of single layers, nested inside a phase
+LAYER_SPANS = ("sched.admit", "sched.reserve", "kv.register_prefix",
+               "kv.copies", "kv.residency")
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_ANNOTATION = {p: f"engine.{p}" for p in HOST_PHASES}
 
 # ---- Chrome trace track model ---- #
 PID_REQUESTS = 1         # one thread (track) per request id
@@ -70,12 +114,42 @@ class _ReqTrace:
                                        # labelling re-prefill as recompute)
     n_preemptions: int = 0
     done: bool = False
+    # host wall clock (perf_counter seconds)
+    wall_submit: float = 0.0
+    wall_admit: Optional[float] = None     # first admission
+    wall_tokens: List[float] = field(default_factory=list)
+
+
+class _LayerSpan:
+    """One layer span: a profiler annotation and its ``host_s`` seconds."""
+    __slots__ = ("_rec", "_name", "_ann", "_t0")
+
+    def __init__(self, rec: "TraceRecorder", name: str):
+        self._rec, self._name = rec, name
+
+    def __enter__(self) -> "_LayerSpan":
+        self._ann = jax.profiler.TraceAnnotation(self._name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._rec._add_host(self._name, t - self._t0)
+
+
+def layer_span(tracer, name: str):
+    """``tracer.layer(name)``, or nothing when there is no recorder."""
+    return nullcontext() if tracer is None else tracer.layer(name)
 
 
 class TraceRecorder:
     """Collects virtual-clock spans/instants and exports trace,
-    breakdown, and SLO reports. All times are seconds on the engine's
-    virtual clock (wall + absorbed migration stall)."""
+    breakdown, and SLO reports. Their times are seconds on the engine's
+    virtual clock (wall + absorbed migration stall); the host phases,
+    layer spans, compile counts and ``wall`` stamps read the host's
+    wall clock."""
 
     def __init__(self) -> None:
         self._req: Dict[int, _ReqTrace] = {}
@@ -86,6 +160,76 @@ class TraceRecorder:
         self.dma_bytes: Dict[str, float] = {}
         self._t_base: Optional[float] = None
         self.t_final: Optional[float] = None
+        # host wall clock: phase and layer-span seconds and counts, and
+        # backend compiles by the program compiled
+        self.host_s: Dict[str, float] = {}
+        self.host_n: Dict[str, int] = {}
+        self.compiles: Dict[str, int] = {}
+        self.n_compiles = 0                # while phases run
+        self._open: Optional[tuple] = None  # (phase, t0, annotation, n0)
+        self._t_first: Optional[float] = None
+        self._t_last: Optional[float] = None
+
+    # ------------------ host phases on the wall clock ------------------ #
+    def phase(self, name: str, **meta) -> None:
+        """Close the open host phase and open ``name`` (one clock read),
+        as profiler annotation ``engine.<name>`` carrying ``meta``. The
+        first call starts counting backend compiles."""
+        if name not in HOST_PHASES:
+            raise ValueError(f"unknown host phase {name!r}")
+        if self._t_last is not None:
+            raise RuntimeError("host phases already ended")
+        t = time.perf_counter()
+        if self._open is None:
+            self._t_first = t
+            jax.monitoring.register_event_duration_secs_listener(
+                self._on_duration)
+        else:
+            self._close(t)
+        ann = jax.profiler.TraceAnnotation(_ANNOTATION[name])
+        ann.__enter__()
+        if meta:
+            ann.set_metadata(**meta)
+        self._open = (name, t, ann, self.n_compiles)
+
+    def phase_elapsed(self) -> float:
+        """Seconds the open phase has run: a run phase's program time."""
+        return time.perf_counter() - self._open[1]
+
+    def end_phases(self) -> None:
+        """Close the open phase and stop counting compiles."""
+        if self._open is None:
+            return
+        t = time.perf_counter()
+        self._close(t)
+        self._open = None
+        self._t_last = t
+        jax.monitoring.unregister_event_duration_listener(self._on_duration)
+
+    def _close(self, t: float) -> None:
+        name, t0, ann, n0 = self._open
+        if self.n_compiles > n0:
+            ann.set_metadata(compiled=self.n_compiles - n0)
+        ann.__exit__(None, None, None)
+        self._add_host(name, t - t0)
+
+    def _add_host(self, name: str, secs: float) -> None:
+        self.host_s[name] = self.host_s.get(name, 0.0) + secs
+        self.host_n[name] = self.host_n.get(name, 0) + 1
+
+    def _on_duration(self, event: str, duration: float, *args,
+                     fun_name: str = "?", **kwargs) -> None:
+        if event == COMPILE_EVENT:
+            self.n_compiles += 1
+            if fun_name.startswith("jit(") and fun_name.endswith(")"):
+                fun_name = fun_name[4:-1]       # "jit(<program>)"
+            self.compiles[fun_name] = self.compiles.get(fun_name, 0) + 1
+
+    def layer(self, name: str) -> _LayerSpan:
+        """A span of one layer (``LAYER_SPANS``) inside the open phase."""
+        if name not in LAYER_SPANS:
+            raise ValueError(f"unknown layer span {name!r}")
+        return _LayerSpan(self, name)
 
     # ------------------------- raw event plumbing ---------------------- #
     def _base(self, t: float) -> None:
@@ -174,7 +318,8 @@ class TraceRecorder:
     # --------------------- per-request lifecycle ----------------------- #
     def submit(self, rid: int, t: float) -> None:
         self._base(t)
-        self._req[rid] = _ReqTrace(rid=rid, t_submit=t, cursor=t)
+        self._req[rid] = _ReqTrace(rid=rid, t_submit=t, cursor=t,
+                                   wall_submit=time.perf_counter())
 
     def _fill(self, r: _ReqTrace, t: float) -> None:
         """Tile the gap up to ``t`` as queue time (waiting for service)."""
@@ -186,6 +331,8 @@ class TraceRecorder:
     def admit(self, rid: int, t: float, *, cached_tokens: int = 0,
               slot: Optional[int] = None) -> None:
         r = self._req[rid]
+        if r.wall_admit is None:
+            r.wall_admit = time.perf_counter()
         self._fill(r, t)                  # submit -> admit wait, explicit
         args = {"cached_tokens": cached_tokens}
         if slot is not None:
@@ -232,7 +379,9 @@ class TraceRecorder:
         r.prefill_hw = max(r.prefill_hw, end_tok)
 
     def token(self, rid: int, t: float, tok: int) -> None:
+        wall = time.perf_counter()
         r = self._req[rid]
+        r.wall_tokens.append(wall)
         name = "first_token" if not r.token_t else "token"
         r.token_t.append(t)
         self._instant_event(PID_REQUESTS, rid, name, t, {"tok": tok})
@@ -292,6 +441,29 @@ class TraceRecorder:
         out = {f"{p}_ms": round(total[f"{p}_s"] * 1e3, ndigits)
                for p in PHASES}
         out["e2e_ms"] = round(e2e * 1e3, ndigits)
+        return out
+
+    # ------------------------ wall-clock requests ----------------------- #
+    def wall(self, rid: int) -> Dict[str, object]:
+        """Request ``rid`` on the host's wall clock: seconds from submit
+        to first admission (``queue_s``) and to first token (``ttft_s``,
+        None before it), and its token stamps (``perf_counter`` s)."""
+        r = self._req[rid]
+        return {"queue_s": (None if r.wall_admit is None
+                            else r.wall_admit - r.wall_submit),
+                "ttft_s": (r.wall_tokens[0] - r.wall_submit
+                           if r.wall_tokens else None),
+                "token_t": list(r.wall_tokens)}
+
+    def wall_summary_ms(self, ndigits: int = 3) -> Dict[str, float]:
+        """p50/p90 of the requests' wall TTFT and queue wait, in ms."""
+        walls = [self.wall(rid) for rid in sorted(self._req)]
+        out = {}
+        for key in ("ttft", "queue"):
+            xs = [w[f"{key}_s"] for w in walls if w[f"{key}_s"] is not None]
+            for q in (50, 90):
+                out[f"{key}_p{q}_ms"] = round(
+                    metrics.percentile(xs, q) * 1e3, ndigits)
         return out
 
     # --------------------------- SLO goodput --------------------------- #
@@ -383,7 +555,9 @@ class TraceRecorder:
           sets and the emitted-token count;
         * the per-"src->dst" DMA span bytes match the manager's
           ``channel_bytes`` counters (SS17 per-channel accounting), when
-          given.
+          given;
+        * when host phases ran, their seconds sum to the wall time from
+          the first phase to this call (which ends them).
 
         Returns a report dict; with ``strict`` raises ``AssertionError``
         listing every failed check (counters may not silently drift)."""
@@ -440,9 +614,18 @@ class TraceRecorder:
         if n_tok != new_tokens:
             fails.append(f"tokens: trace {n_tok} != stats {new_tokens}")
 
+        wall_s = None
+        if self._t_first is not None:
+            self.end_phases()
+            wall_s = self._t_last - self._t_first
+            tiled = sum(self.host_s.get(p, 0.0) for p in HOST_PHASES)
+            if not close(tiled, wall_s):
+                fails.append(f"host phases: {tiled:.9f} s tiled != wall "
+                             f"{wall_s:.9f} s")
+
         report = {"ok": not fails, "failures": fails,
                   "n_requests": len(self._req), "n_tokens": n_tok,
-                  "stall_s": self.stall_total}
+                  "stall_s": self.stall_total, "wall_s": wall_s}
         if strict and fails:
             raise AssertionError(
                 "trace/stats drift detected:\n  " + "\n  ".join(fails))

@@ -5,14 +5,16 @@ memory-space rules; the TPU compiler, which compiles for a described chip
 without one attached, can. Each case lowers one kernel at llama3.2-1b
 widths (32 query heads of 64; 8 KV heads as in the released model, or 32
 as in ``configs/llama32_1b.py``) for one chip of a described ``v5e:2x2``
-topology and checks that the kernel survived as a ``tpu_custom_call``.
-Nothing runs, so these say nothing about results or times.
+topology and checks that the kernel survived as a ``tpu_custom_call``
+under its stable name (the ``pallas_call`` ``name=``, which a device
+trace shows). Nothing runs, so these say nothing about results or times.
 
 The topology is described only inside the module fixture: the TPU library
 admits one process at a time, and a description made at import time
 would make pytest workers collect different tests.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,7 +50,15 @@ def one_chip():
 
 def _compile_text(fn, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    # under a program of another name, as in the engine, so that only the
+    # kernel's own name can name its instruction
+    return jax.jit(lambda *a: fn(*a)).lower(*args).compile().as_text()
+
+
+def _named_kernel(text, name):
+    """The compiled kernel is an instruction ``%<name>[.n] = ...
+    custom-call(...)``: the name a device trace shows for it."""
+    return re.search(rf"%{name}(\.\d+)? = [^\n]*custom-call", text)
 
 
 def _pool(page_size, dtype, n_seqs, n_kv_heads=HKV):
@@ -73,6 +83,7 @@ def test_paged_decode_compiles(one_chip, kv_dtype, page_size, n_kv_heads):
         ((B, npp), jnp.int32), ((B,), jnp.int32),
         ((n_kv_heads,), jnp.float32), ((n_kv_heads,), jnp.float32))
     assert "tpu_custom_call" in text
+    assert _named_kernel(text, "paged_decode_attention")
 
 
 def test_chunk_prefill_compiles(one_chip):
@@ -83,6 +94,7 @@ def test_chunk_prefill_compiles(one_chip):
         _pool(page_size, jnp.bfloat16, 1), _pool(page_size, jnp.bfloat16, 1),
         ((1, npp), jnp.int32), ((), jnp.int32), ((1,), jnp.int32))
     assert "tpu_custom_call" in text
+    assert _named_kernel(text, "chunk_prefill_attention")
 
 
 def test_spec_verify_compiles(one_chip):
@@ -93,6 +105,8 @@ def test_spec_verify_compiles(one_chip):
         _pool(page_size, jnp.bfloat16, B), _pool(page_size, jnp.bfloat16, B),
         ((B, npp), jnp.int32), ((B,), jnp.int32), ((B,), jnp.int32))
     assert "tpu_custom_call" in text
+    # the verify pass runs the chunk-prefill kernel
+    assert _named_kernel(text, "chunk_prefill_attention")
 
 
 def test_flash_attention_compiles(one_chip):
@@ -101,3 +115,4 @@ def test_flash_attention_compiles(one_chip):
         fa.flash_attention, one_chip, ((1, S, H, DH), jnp.bfloat16),
         ((1, S, HKV, DH), jnp.bfloat16), ((1, S, HKV, DH), jnp.bfloat16))
     assert "tpu_custom_call" in text
+    assert _named_kernel(text, "flash_attention")

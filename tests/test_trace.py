@@ -404,3 +404,213 @@ def test_second_serve_on_same_engine_reconciles(small_model):
     assert eng.trace is not first                  # fresh recorder
     assert eng.trace_report["ok"], eng.trace_report["failures"]
     assert len(eng.stats.ttft) == 4                # totals kept growing
+
+
+# ------------------- host phases on the wall clock ----------------------- #
+
+def _host_phase_engine(small_model, path):
+    """A serve down one path of the loop: plain, prefix-shared,
+    preempting or speculative."""
+    from repro.serving import ServeEngine
+
+    cfg, opts, params = small_model
+    rng = np.random.default_rng(11)
+    kw = dict(max_len=72, scheduler="continuous", page_size=8, max_batch=2)
+    new = 8
+    if path == "plain":
+        reqs = [rng.integers(1, cfg.vocab, size=n).tolist() for n in (13, 21)]
+    elif path == "prefix":
+        doc = rng.integers(1, cfg.vocab, size=40).tolist()
+        reqs = [doc + rng.integers(1, cfg.vocab, size=3).tolist()
+                for _ in range(3)]
+    elif path == "preempt":
+        reqs = [list(range(1, 5)), list(range(5, 9))]
+        kw.update(max_len=32, page_size=4, n_pages=6, decode_lookahead=4,
+                  prefix_cache=False)
+        new = 12
+    else:
+        doc = rng.integers(1, cfg.vocab, size=32).tolist()
+        reqs = [doc + rng.integers(1, cfg.vocab, size=4).tolist()
+                for _ in range(2)]
+        kw.update(spec_mode="ngram", spec_k=4)
+        new = 16
+    eng = ServeEngine(cfg, params, opts, **kw)
+    eng.serve([r[:] for r in reqs], new)
+    return eng
+
+
+@pytest.mark.parametrize("path", ["plain", "prefix", "preempt", "spec"])
+def test_host_phases_tile_every_serve(small_model, path):
+    """One host phase is open from entry to reconcile, so the phases'
+    seconds sum to the serve's wall time; each run phase ran once per
+    program the virtual trace shows, and each path's own layer spans
+    appear."""
+    from repro.serving.trace import HOST_PHASES, LAYER_SPANS
+
+    eng = _host_phase_engine(small_model, path)
+    tr, rep = eng.trace, eng.trace_report
+    assert rep["ok"], rep["failures"]
+    assert rep["wall_s"] > 0
+    tiled = sum(tr.host_s.get(p, 0.0) for p in HOST_PHASES)
+    assert abs(tiled - rep["wall_s"]) <= 1e-6
+    assert set(tr.host_s) <= set(HOST_PHASES) | set(LAYER_SPANS)
+    assert set(tr.host_s) == set(tr.host_n)
+    assert tr.host_n["setup"] == tr.host_n["finish"] == 1
+    ev = tr.to_chrome()["traceEvents"]
+
+    def n_spans(name):
+        return sum(1 for e in ev if e["ph"] == "X" and e["name"] == name)
+    assert tr.host_n["prefill.run"] == n_spans("prefill_chunk")
+    assert tr.host_n.get("decode.run", 0) == n_spans("decode_block")
+    assert tr.host_n.get("spec.run", 0) == n_spans("spec_verify")
+    # a preempted request's re-prefill ends in a token pull too
+    assert tr.host_n["first_token"] >= len(eng.stats.ttft)
+    assert {"sched.admit", "kv.copies", "kv.residency"} <= set(tr.host_s)
+    # nested layer spans lie inside the phases
+    layers = sum(tr.host_s[s] for s in ("sched.admit", "kv.residency"))
+    assert layers <= tiled
+    if path == "prefix":
+        assert eng.stats.cached_prefix_tokens > 0
+        assert tr.host_n["kv.register_prefix"] >= tr.host_n["prefill.run"]
+    if path == "preempt":
+        assert eng.stats.preemptions >= 1
+        assert "sched.reserve" in tr.host_s
+    if path == "spec":
+        assert tr.host_n["spec.propose"] >= tr.host_n["spec.run"] > 0
+        assert "decode.run" not in tr.host_s
+    # wall stamps: one per token, in order, after each request's submit
+    for rid, out in enumerate(eng.trace.breakdowns().values()):
+        w = tr.wall(rid)
+        assert len(w["token_t"]) == out["n_tokens"]
+        assert w["token_t"] == sorted(w["token_t"])
+        assert 0 <= w["queue_s"] <= w["ttft_s"] <= rep["wall_s"]
+
+
+def test_reconcile_raises_when_a_host_phase_is_left_out():
+    tr = TraceRecorder()
+    tr.phase("setup")
+    tr.phase("admit")
+    with tr.layer("sched.admit"):
+        pass
+    tr.phase("finish")
+    rep = tr.reconcile(stall_s=0.0, ttft=[], itl=[], new_tokens=0)
+    assert rep["ok"] and rep["wall_s"] >= tr.host_s["admit"]
+    assert tr.host_n == {"setup": 1, "admit": 1, "sched.admit": 1,
+                         "finish": 1}
+    with pytest.raises(RuntimeError, match="ended"):
+        tr.phase("admit")               # phases end at reconcile
+
+    tr = TraceRecorder()
+    tr.phase("setup")
+    tr.phase("admit")
+    tr.phase("finish")
+    tr.end_phases()
+    del tr.host_s["admit"]              # a phase's seconds went missing
+    with pytest.raises(AssertionError, match="host phases"):
+        tr.reconcile(stall_s=0.0, ttft=[], itl=[], new_tokens=0)
+    with pytest.raises(ValueError, match="unknown host phase"):
+        TraceRecorder().phase("idle")
+    with pytest.raises(ValueError, match="unknown layer span"):
+        TraceRecorder().layer("kv.other")
+
+
+def _lowered_module(small_model, program):
+    """The module name of one engine program, lowered at a tiny size."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from repro.models import init_cache, init_paged_cache
+    from repro.serving import ServeEngine
+
+    cfg, opts, params = small_model
+    eng = ServeEngine(cfg, params, opts, max_len=32, page_size=8,
+                      max_batch=2, scheduler="continuous",
+                      temperature=0.5)
+    B, npp, V = 2, 4, cfg.vocab
+    pool = init_paged_cache(cfg, 9, 8, opts)
+    dense = init_cache(cfg, B, 32, opts)
+    def i32(*shape):
+        return jnp.zeros(shape, jnp.int32)
+    keys = eng._block_keys(i32(B), i32(B))
+    calls = {
+        "_prefill": lambda: eng._prefill.lower(params, i32(B, 8), dense),
+        "_decode": lambda: eng._decode.lower(params, i32(B), jnp.int32(8),
+                                             dense),
+        "_decode_block": lambda: eng._decode_block.lower(
+            params, i32(B), jnp.int32(8), dense, n_steps=2),
+        "_prefill_chunk": lambda: eng._prefill_chunk.lower(
+            params, i32(1, 16), pool, i32(1, npp), jnp.int32(0),
+            i32(1), calibrate=False),
+        "_decode_fused": lambda: eng._decode_fused.lower(
+            params, i32(B), i32(B), i32(B, npp), pool, n_steps=2,
+            keys=keys, done=jnp.zeros((B,), bool), quota=i32(B)),
+        "_spec_verify": lambda: eng._spec_verify.lower(
+            params, i32(B, 3), i32(B), i32(B), i32(B, npp), pool, keys),
+        "_copy_pages": lambda: eng._copy_pages.lower(pool, i32(2, 2)),
+        "_block_keys": lambda: eng._block_keys.lower(i32(B), i32(B)),
+        "_sample1": lambda: eng._sample1.lower(
+            jnp.zeros((1, V), jnp.float32), keys[:1]),
+    }
+    text = calls[program]().as_text()
+    return re.search(r"module @(\S+)", text).group(1)
+
+
+@pytest.mark.parametrize("program,function", [
+    ("_prefill", "prefill"), ("_decode", "decode_step"),
+    ("_decode_block", "decode_steps"),
+    ("_prefill_chunk", "prefill_paged_chunk"),
+    ("_decode_fused", "decode_steps_paged"),
+    ("_spec_verify", "spec_decode_verify"), ("_copy_pages", "copy_pages"),
+    ("_block_keys", "block_keys"), ("_sample1", "sample")])
+def test_engine_programs_lower_to_their_names(small_model, program,
+                                              function):
+    """A jitted bare ``functools.partial`` lowers to ``jit__unknown``;
+    every engine program carries its function's name instead, which is
+    the name a device trace shows for it."""
+    assert _lowered_module(small_model, program) == f"jit_{function}"
+
+
+def test_second_same_shape_serve_compiles_no_program(small_model):
+    """The compile listener counts by program while a serve runs: the
+    first serve compiles the engine's programs, a second of the same
+    shapes compiles nothing, and the listener is gone afterwards."""
+    from jax._src import monitoring
+    from repro.serving import ServeEngine
+
+    cfg, opts, params = small_model
+    rng = np.random.default_rng(5)
+    reqs = [rng.integers(1, cfg.vocab, size=n).tolist() for n in (9, 17)]
+    eng = ServeEngine(cfg, params, opts, max_len=48,
+                      scheduler="continuous", page_size=8, max_batch=2,
+                      decode_lookahead=3)
+    before = len(monitoring.get_event_duration_listeners())
+    eng.serve([r[:] for r in reqs], 6)
+    first = dict(eng.trace.compiles)
+    eng.serve([r[:] for r in reqs], 6)
+    assert {"prefill_paged_chunk", "decode_steps_paged"} <= set(first)
+    assert eng.trace.compiles == {}
+    assert eng.trace.n_compiles == 0
+    assert len(monitoring.get_event_duration_listeners()) == before
+
+
+def test_failed_serve_ends_its_phases(small_model):
+    """A serve that raises mid-loop still closes its open phase and
+    drops its compile listener."""
+    from jax._src import monitoring
+    from repro.serving import ServeEngine
+
+    cfg, opts, params = small_model
+    eng = ServeEngine(cfg, params, opts, max_len=32,
+                      scheduler="continuous", page_size=8, max_batch=2)
+
+    def broken(*args, **kwargs):
+        raise RuntimeError("device lost")
+    eng._prefill_chunk = broken
+    before = len(monitoring.get_event_duration_listeners())
+    with pytest.raises(RuntimeError, match="device lost"):
+        eng.serve([[1, 2, 3]], 4)
+    assert len(monitoring.get_event_duration_listeners()) == before
+    assert eng.trace.host_n["prefill.run"] == 1
+    with pytest.raises(RuntimeError, match="ended"):
+        eng.trace.phase("admit")
