@@ -542,13 +542,17 @@ def _head_shards(opts: RuntimeOptions, n_kv_heads: int) -> int:
     return ksh.head_shards(opts.kv_shard_mesh, n_kv_heads)
 
 
-def _gather_pages(pages, page_table):
+def _gather_pages(pages, page_table, layer=None):
     """Head-major (n_pages, Hkv, ps, dh) pool rows of each sequence's
-    page table -> dense (B, n_pp * ps, Hkv, dh), for the XLA path."""
+    page table -> dense (B, n_pp * ps, Hkv, dh), for the XLA path.
+
+    With ``layer``, ``pages`` is the whole (n_layers, n_pages, ...) pool
+    and one gather indexes (layer, page): slicing the layer out first
+    would copy it."""
     B, n_pp = page_table.shape
-    Hkv, ps, dh = pages.shape[1:]
-    return (pages[page_table].transpose(0, 1, 3, 2, 4)
-            .reshape(B, n_pp * ps, Hkv, dh))
+    Hkv, ps, dh = pages.shape[-3:]
+    g = pages[page_table] if layer is None else pages[layer, page_table]
+    return g.transpose(0, 1, 3, 2, 4).reshape(B, n_pp * ps, Hkv, dh)
 
 
 def _scatter_pages(pages, pid, off, rows):
@@ -558,20 +562,65 @@ def _scatter_pages(pages, pid, off, rows):
     return pages.at[pid, :, off].set(rows)
 
 
+def _window_pages(page_table, start, n_valid, C: int, ps: int):
+    """The pages a (B, C) chunk at per-row ``start`` writes, whole.
+
+    A chunk touches at most ``ceil((C + ps - 1) / ps)`` pages of each
+    row, whatever its alignment (3 for C=32, ps=16). Returns (pid (B, W)
+    physical page ids, row (B, W, ps) the chunk row that lands in each
+    page slot, ok (B, W, ps) whether it does). A slot is written when
+    its row lies in the chunk, before ``n_valid`` and inside the page
+    table. A window page that no slot of its row writes goes to the null
+    page: rewriting it unchanged could undo another row's write to a
+    page the two tables share."""
+    B, n_pp = page_table.shape
+    W = (C + 2 * ps - 2) // ps
+    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
+    blk = start[:, None] // ps + jnp.arange(W)[None, :]           # (B, W)
+    pos = blk[:, :, None] * ps + jnp.arange(ps)                   # (B, W, ps)
+    row = pos - start[:, None, None]
+    ok = ((row >= 0) & (row < C) & (pos < n_valid[:, None, None])
+          & (blk < n_pp)[:, :, None])
+    # a block past the table has no slot in ``ok``; clamp it in range
+    pid = jnp.take_along_axis(page_table, jnp.minimum(blk, n_pp - 1), axis=1)
+    pid = jnp.where(ok.any(-1), pid, 0)
+    return pid, row, ok
+
+
+def _write_window(pool, layer, pid, row, ok, rows):
+    """Merge a chunk's (B, C, Hkv, dh) ``rows`` into layer ``layer`` of
+    the whole (n_layers, n_pages, Hkv, ps, dh) ``pool`` at the window of
+    ``_window_pages``: gather the window's pages at (layer, pid), take
+    the chunk's rows where ``ok`` holds, scatter whole pages back. Only
+    pages move, so the pool is updated in place; a token-row scatter
+    would have the compiler re-lay the pool out around it."""
+    B, W, ps = row.shape
+    C = rows.shape[1]
+    src = rows[jnp.arange(B)[:, None], jnp.clip(row, 0, C - 1).reshape(B, -1)]
+    new = src.reshape(B, W, ps, *rows.shape[2:]).transpose(0, 1, 3, 2, 4)
+    pages = jnp.where(ok[:, :, None, :, None], new.astype(pool.dtype),
+                      pool[layer, pid])
+    return pool.at[layer, pid].set(pages)
+
+
 def _chunk_attend(q, kp, vp, ksc, vsc, page_table, start, n_valid, *,
-                  cfg: ArchConfig, opts: RuntimeOptions):
+                  cfg: ArchConfig, opts: RuntimeOptions, layer=None):
     """Attend a (B, C, H', hd) query chunk over pooled pages.
 
     Head counts come from the operands, not ``cfg``, so the same body
     serves the replicated pool AND one head shard of it (the per-shard
     body under ``kernels.sharded.sharded_attend``). ``ksc``/``vsc`` are
-    the int8 per-head scales matching kp/vp's head slice, or None."""
+    the int8 per-head scales matching kp/vp's head slice, or None.
+    kp/vp are one layer's pages, or with ``layer`` the whole pool."""
     B, C, H, hd = q.shape
-    Hkv, ps = kp.shape[1], kp.shape[2]
+    Hkv, ps = kp.shape[-3], kp.shape[-2]
     n_pp = page_table.shape[1]
     quant = ksc is not None
     if opts.attn_impl == "pallas":
         from repro.kernels import ops as kops
+        if layer is not None:
+            # the kernels take one layer's pages: this slice copies it
+            kp, vp = kp[layer], vp[layer]
         if jnp.ndim(start) == 1:
             # per-sequence window start => speculative-verify entry (SS14)
             return kops.spec_verify_attention(
@@ -582,8 +631,8 @@ def _chunk_attend(q, kp, vp, ksc, vsc, page_table, start, n_valid, *,
             q, kp, vp, page_table, start, n_valid, scale=hd ** -0.5,
             k_scale=ksc, v_scale=vsc, softcap=cfg.logit_softcap)
     # XLA path: gather the pages densely, causal-mask by position
-    kd = _gather_pages(kp, page_table)
-    vd = _gather_pages(vp, page_table)
+    kd = _gather_pages(kp, page_table, layer)
+    vd = _gather_pages(vp, page_table, layer)
     if quant:
         kd = kd.astype(q.dtype) * ksc[None, None, :, None].astype(q.dtype)
         vd = vd.astype(q.dtype) * vsc[None, None, :, None].astype(q.dtype)
@@ -666,13 +715,17 @@ def prefill_paged(cfg: ArchConfig, params, tokens, cache, page_table,
 
 
 def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
-                      cache_layer, positions, page_table, start, n_valid, *,
-                      calibrate: bool):
-    """Chunk-prefill attention against pooled KV pages. x: (B, C, d).
+                      kpool, vpool, layer, scales, positions, page_table,
+                      start, n_valid, *, calibrate: bool):
+    """Chunk-prefill attention of layer ``layer`` against the whole
+    pooled KV pages (n_layers, n_pages, Hkv, ps, dh). x: (B, C, d).
 
-    Scatters the chunk's KV into the pages covering ``positions`` first,
-    then attends causally (by absolute position) across every page the
-    sequence owns — previously cached prefix pages included."""
+    Writes the chunk's KV into the pages covering ``positions`` first,
+    whole pages at a time (``_write_window``), then attends causally (by
+    absolute position) across every page the sequence owns — previously
+    cached prefix pages included. ``scales``: this layer's int8 per-head
+    scales ({"k_scale", "v_scale"}), or {}. Returns (out, kpool, vpool,
+    scales)."""
     B, C, _ = x.shape
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = cm.dense(p["wq"], x).reshape(B, C, H, hd)
@@ -680,10 +733,7 @@ def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
     v = cm.dense(p["wv"], x).reshape(B, C, Hkv, hd)
     q = cm.apply_rope(q, positions)
     k = cm.apply_rope(k, positions)
-    quant = "k_scale" in cache_layer
-    kp, vp = cache_layer["k"], cache_layer["v"]
-    ps = kp.shape[2]
-    n_pp = page_table.shape[1]
+    quant = bool(scales)
 
     if quant:
         if calibrate:
@@ -693,30 +743,28 @@ def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
             ksc = _amax_scale(jnp.where(ok, k, 0), (0, 1, 3))
             vsc = _amax_scale(jnp.where(ok, v, 0), (0, 1, 3))
         else:
-            ksc, vsc = cache_layer["k_scale"], cache_layer["v_scale"]
+            ksc, vsc = scales["k_scale"], scales["v_scale"]
         k_store = _quantize_with(k, ksc[None, None]).astype(jnp.int8)
         v_store = _quantize_with(v, vsc[None, None]).astype(jnp.int8)
     else:
         ksc = vsc = None
-        k_store, v_store = k.astype(kp.dtype), v.astype(vp.dtype)
+        k_store, v_store = k, v
 
-    # scatter the chunk's KV at absolute positions [start, start + C); pad
-    # positions past the reserve land on the null page (entries past the
-    # sequence's pages are 0, and positions past the table are clipped to 0
-    # explicitly — gather would silently clamp to the LAST entry)
-    blk = positions // ps
-    pid = jnp.take_along_axis(page_table, jnp.minimum(blk, n_pp - 1), axis=1)
-    pid = jnp.where(blk < n_pp, pid, 0).reshape(-1)             # (B * C,)
-    off = (positions % ps).reshape(-1)
-    kp = _scatter_pages(kp, pid, off, k_store.reshape(B * C, Hkv, hd))
-    vp = _scatter_pages(vp, pid, off, v_store.reshape(B * C, Hkv, hd))
+    # write the chunk's KV at absolute positions [start, start + C); pad
+    # rows past n_valid are not written, and pages past the sequence's
+    # reserve (table entries 0, or past the table) are the null page
+    pid, row, ok = _window_pages(page_table, start, n_valid, C,
+                                 kpool.shape[3])
+    kpool = _write_window(kpool, layer, pid, row, ok, k_store)
+    vpool = _write_window(vpool, layer, pid, row, ok, v_store)
 
     n_sh = _head_shards(opts, Hkv)
     if n_sh:
         # head-sharded attend (SS16): the pool/q head dims partition over
-        # the mesh, the scatter above already ran shard-wise under GSPMD,
+        # the mesh, the write above already ran shard-wise under GSPMD,
         # and the per-shard body below is this very function's replicated
-        # path on an Hkv/N slice — bitwise identical after the gather
+        # path on an Hkv/N slice — bitwise identical after the gather.
+        # It takes one layer's pages, so this slice copies the layer.
         from repro.kernels import sharded as ksh
 
         def attend(q_l, kp_l, vp_l, ks_l, vs_l, pt, st, nv):
@@ -726,19 +774,46 @@ def _paged_chunk_attn(p, x, cfg: ArchConfig, opts: RuntimeOptions,
                                  pt, st, nv, cfg=cfg, opts=opts)
         ones = jnp.ones((Hkv,), jnp.float32)
         out = ksh.sharded_attend(
-            opts.kv_shard_mesh, attend, q, kp, vp,
+            opts.kv_shard_mesh, attend, q, kpool[layer], vpool[layer],
             ksc if quant else ones, vsc if quant else ones,
             (page_table, jnp.asarray(start, jnp.int32), n_valid),
             q_head_axis=2)
     else:
-        out = _chunk_attend(q, kp, vp, ksc, vsc, page_table, start,
-                            n_valid, cfg=cfg, opts=opts)
+        out = _chunk_attend(q, kpool, vpool, ksc, vsc, page_table, start,
+                            n_valid, cfg=cfg, opts=opts, layer=layer)
     out = cm.dense(p["wo"], out.reshape(B, C, H * hd))
-    new_cache = {"k": kp, "v": vp}
-    if quant:
-        new_cache["k_scale"] = ksc
-        new_cache["v_scale"] = vsc
-    return out, new_cache
+    new_scales = {"k_scale": ksc, "v_scale": vsc} if quant else {}
+    return out, kpool, vpool, new_scales
+
+
+def _paged_chunk_layers(cfg: ArchConfig, params, x, cache, positions,
+                        page_table, start, n_valid, opts: RuntimeOptions,
+                        calibrate: bool):
+    """The layer stack over a (B, C) chunk against the paged pool.
+
+    The pool's k and v ride in the scan's carry and every layer writes
+    them in place (``_paged_chunk_attn``): with the pool as scan xs/ys,
+    each layer would slice its layer out and the scan would stack a
+    fresh copy of the pool. The int8 per-layer scales, small, stay xs
+    and ys. Returns (x, new cache)."""
+    st = cache["stack"]
+    scales = {n: st[n] for n in ("k_scale", "v_scale") if n in st}
+
+    def scan_body(carry, xs):
+        h, kp, vp = carry
+        lp, layer, sc = xs
+        h = cm.constrain(h, opts.residual_sharding)
+        a, kp, vp, sc = _paged_chunk_attn(
+            lp["attn"], cm.rms_norm(h, lp["ln1"]), cfg, opts, kp, vp,
+            layer, sc, positions, page_table, start, n_valid,
+            calibrate=calibrate)
+        h = h + a
+        f, _ = _ffn_apply(lp, cm.rms_norm(h, lp["ln2"]), cfg, opts)
+        return (h + f, kp, vp), sc
+    layers = jnp.arange(st["k"].shape[0], dtype=jnp.int32)
+    (x, kp, vp), sc = jax.lax.scan(scan_body, (x, st["k"], st["v"]),
+                                   (params["stack"], layers, scales))
+    return x, {"stack": {"k": kp, "v": vp, **sc}}
 
 
 def prefill_paged_chunk(cfg: ArchConfig, params, tokens, cache, page_table,
@@ -762,20 +837,10 @@ def prefill_paged_chunk(cfg: ArchConfig, params, tokens, cache, page_table,
     x = _embed_tokens(cfg, params, tokens, None)
     start = jnp.asarray(start, jnp.int32)
     positions = jnp.broadcast_to(start + jnp.arange(C)[None, :], (B, C))
-
-    def scan_body(carry, xs):
-        lp, cl = xs
-        h = cm.constrain(carry, opts.residual_sharding)
-        a, nc = _paged_chunk_attn(lp["attn"], cm.rms_norm(h, lp["ln1"]),
-                                  cfg, opts, cl, positions, page_table,
-                                  start, n_valid, calibrate=calibrate)
-        h = h + a
-        f, _ = _ffn_apply(lp, cm.rms_norm(h, lp["ln2"]), cfg, opts)
-        return h + f, nc
-    x, new_stack = jax.lax.scan(scan_body, x, (params["stack"],
-                                               cache["stack"]))
-    logits = _logits(cfg, params, x)
-    return logits, {"stack": new_stack}
+    x, cache = _paged_chunk_layers(cfg, params, x, cache, positions,
+                                   page_table, start, n_valid, opts,
+                                   calibrate)
+    return _logits(cfg, params, x), cache
 
 
 def copy_pages(cache, pairs):
@@ -1022,28 +1087,17 @@ def decode_verify_paged(cfg: ArchConfig, params, tokens, seq_lens, n_fed,
 
     Logits row j of slot b is the target distribution for the token AFTER
     window token j — rows 0..n_fed-2 verify the draft, row n_fed-1 is the
-    correction/bonus row. Pad rows write KV beyond the fed window into
-    reserved (or null) pages: never committed, overwritten before any
-    read. Returns (logits (B, C, vocab), new cache)."""
+    correction/bonus row. Pad rows beyond the fed window write no KV.
+    Returns (logits (B, C, vocab), new cache)."""
     B, C = tokens.shape
     x = _embed_tokens(cfg, params, jnp.asarray(tokens, jnp.int32), None)
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     n_valid = seq_lens + jnp.asarray(n_fed, jnp.int32)
     positions = seq_lens[:, None] + jnp.arange(C)[None, :]
-
-    def scan_body(carry, xs):
-        lp, cl = xs
-        h = cm.constrain(carry, opts.residual_sharding)
-        a, nc = _paged_chunk_attn(lp["attn"], cm.rms_norm(h, lp["ln1"]),
-                                  cfg, opts, cl, positions, page_table,
-                                  seq_lens, n_valid, calibrate=False)
-        h = h + a
-        f, _ = _ffn_apply(lp, cm.rms_norm(h, lp["ln2"]), cfg, opts)
-        return h + f, nc
-    x, new_stack = jax.lax.scan(scan_body, x, (params["stack"],
-                                               cache["stack"]))
-    logits = _logits(cfg, params, x)
-    return logits, {"stack": new_stack}
+    x, cache = _paged_chunk_layers(cfg, params, x, cache, positions,
+                                   page_table, seq_lens, n_valid, opts,
+                                   calibrate=False)
+    return _logits(cfg, params, x), cache
 
 
 def spec_decode_verify(cfg: ArchConfig, params, tokens, draft_len, seq_lens,

@@ -7,12 +7,15 @@ widths (32 query heads of 64; 8 KV heads as in the released model, or 32
 as in ``configs/llama32_1b.py``) for one chip of a described ``v5e:2x2``
 topology and checks that the kernel survived as a ``tpu_custom_call``
 under its stable name (the ``pallas_call`` ``name=``, which a device
-trace shows). Nothing runs, so these say nothing about results or times.
+trace shows). One more case compiles the whole prefill chunk program at
+qwen2.5-3b widths and checks that it writes the KV pool in place.
+Nothing runs, so these say nothing about results or times.
 
 The topology is described only inside the module fixture: the TPU library
 admits one process at a time, and a description made at import time
 would make pytest workers collect different tests.
 """
+import math
 import os
 import re
 
@@ -116,3 +119,80 @@ def test_flash_attention_compiles(one_chip):
         ((1, S, HKV, DH), jnp.bfloat16), ((1, S, HKV, DH), jnp.bfloat16))
     assert "tpu_custom_call" in text
     assert _named_kernel(text, "flash_attention")
+
+
+# ----------------- the prefill chunk program, whole ---------------------- #
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\(")
+_HEADER = re.compile(r"^(?:ENTRY )?%(\S+) \(.*\{$")
+
+
+def _instructions(text):
+    """(computation, name, dims, opcode, called computation) of every
+    array-valued instruction, and each computation's ROOT opcode."""
+    rows, roots, comp = [], {}, None
+    for line in text.splitlines():
+        h = _HEADER.match(line)
+        if h:
+            comp = h.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(",") if d)
+        calls = re.search(r"calls=%([^,\s]+)", line)
+        rows.append((comp, m.group(1), dims, m.group(3),
+                     calls.group(1) if calls else None))
+        if line.lstrip().startswith("ROOT "):
+            roots[comp] = m.group(3)
+    return rows, roots
+
+
+def test_prefill_chunk_program_writes_pool_in_place(one_chip):
+    """The prefill chunk program at qwen2.5-3b widths (two layers, a pool
+    of 1025 pages, a 32-token chunk) carries the pool through its layer
+    scan and writes whole pages into it in place. So no temporary is as
+    large as one layer of the pool, and no instruction results in the
+    pool's or a layer's shape but the parameters, the scan's tuple
+    elements and the scatter into the carried pool: a copy, a
+    dynamic-slice or dynamic-update-slice, or a relayout there would be
+    the compiler copying the pool again."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.models import RuntimeOptions, init_params
+    from repro.models.lm import init_paged_cache, prefill_paged_chunk
+    cfg = dataclasses.replace(get_config("qwen2.5-3b"), n_layers=2)
+    opts = RuntimeOptions(dtype="bfloat16")
+    n_pages, ps, C = 1025, 16, 32
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0), opts)))
+    cache = shapes(jax.eval_shape(
+        lambda: init_paged_cache(cfg, n_pages, ps, opts)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, c, pt, s, n: prefill_paged_chunk(cfg, p, t, c, pt, s,
+                                                      n, opts),
+        donate_argnums=(2,)).lower(
+            params, i32(1, C), cache, i32(1, MAX_LEN // ps), i32(),
+            i32(1)).compile()
+
+    page = (cfg.n_kv_heads, ps, cfg.head_dim)
+    layer_bytes = 2 * n_pages * math.prod(page)       # bf16
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes
+
+    def pool_sized(dims):
+        d = [x for x in dims if x != 1]
+        if len(d) == 5 and d[0] == cfg.n_layers:
+            d = d[1:]
+        return sorted(d) == sorted((n_pages,) + page)
+    rows, roots = _instructions(compiled.as_text())
+    writes = [r for r in rows if pool_sized(r[2]) and (
+        r[3] == "scatter" or (r[3] == "fusion" and roots[r[4]] == "scatter"))]
+    others = [r for r in rows if pool_sized(r[2]) and r not in writes
+              and r[3] not in ("parameter", "get-tuple-element")]
+    assert writes, "no scatter into the carried pool"
+    assert not others, others
