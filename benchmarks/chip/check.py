@@ -2,7 +2,8 @@
 
 After the window, a sample of the requests the window finished, drawn
 from the seed and always holding the longest, is run once through the
-float32 reference over its prompt and its served tokens. For each
+float32 reference of the configuration's family (``families/``) over
+its prompt and its served tokens. For each
 served token the reading is how far its reference logit lies below the
 reference's best logit at that position; the number compared is the
 widest such gap over the sample (``max_logit_gap``). Greedy tokens of a
@@ -20,7 +21,7 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from benchmarks.chip import reference, traffic
+from benchmarks.chip import families, traffic
 
 
 def sample(done: Sequence[Tuple[List[int], List[int]]], seed: int,
@@ -58,18 +59,19 @@ def readings(cfg: dict, seed: int, seqs, *, pad_to: int,
     """``max_logit_gap`` of the served tokens of ``seqs`` ((prompt,
     served) pairs) and, with ``control``, the float8 control's
     ``control_gap`` on the same positions."""
+    ref_logits = families.of(cfg).logits
     out = {"max_logit_gap": 0.0, "tokens": 0}
     if control:
         out["control_gap"] = 0.0
     for prompt, served in seqs:
         seq, rows = _rows(prompt, served)
-        ref = reference.logits(cfg, seed, seq, rows, pad_to=pad_to)
+        ref = ref_logits(cfg, seed, seq, rows, pad_to=pad_to)
         g = _gaps(ref, np.asarray(served))
         out["max_logit_gap"] = max(out["max_logit_gap"], float(g.max()))
         out["tokens"] += len(served)
         if control:
-            low = reference.logits(cfg, seed, seq, rows, pad_to=pad_to,
-                                   quant="fp8")
+            low = ref_logits(cfg, seed, seq, rows, pad_to=pad_to,
+                             quant="fp8")
             gc = _gaps(ref, low.argmax(axis=-1))
             out["control_gap"] = max(out["control_gap"], float(gc.max()))
     return out

@@ -25,14 +25,13 @@ import gc  # noqa: E402
 import json  # noqa: E402
 import time  # noqa: E402
 
-from benchmarks.chip import check, counts, harness, spans  # noqa: E402
-from benchmarks.chip import traffic, weights  # noqa: E402
+from benchmarks.chip import check, harness, spans, traffic  # noqa: E402
 
 
 def _serve_sample(eng, seam, cell, seed, w):
     t0 = time.perf_counter()
-    outs = harness.serve(eng, seam, w.prompts, w.max_new_tokens,
-                         spans.Sink())
+    outs, _ = harness.serve(eng, seam, w.prompts, w.max_new_tokens,
+                            spans.Sink())
     wave_s = time.perf_counter() - t0
     done = [(p, o) for p, o in zip(w.prompts, outs)
             if len(o) == w.max_new_tokens]
@@ -42,17 +41,20 @@ def _serve_sample(eng, seam, cell, seed, w):
 
 def readings(cell, seeds, fp8_seeds, log=print):
     import jax
-    m = counts.Dims.of(cell.config)
-    pad = check.reference.padded_len(cell.sizes["max_len"])
+    fam = cell.family
+    m = fam.dims(cell.config)
+    vocab = cell.config["vocab_size"]
+    pad = fam.padded_len(cell.sizes["max_len"])
+    sharding = harness.weight_sharding(cell)
     out = []
     with spans.stamped() as seam:
         eng = None
         for seed in seeds:
-            params = weights.served_params(m, seed)
+            params = fam.served_params(m, seed, sharding=sharding)
             if eng is None:
                 eng = harness.build_engine(cell, params)
                 harness.warm_up(eng, seam, cell, seed)
-            w = traffic.wave(cell.mix, m.vocab, seed, 0)
+            w = traffic.wave(cell.mix, vocab, seed, 0)
             eng.params = params
             seqs, wave_s, failed = _serve_sample(eng, seam, cell, seed, w)
             eng.params = None

@@ -4,7 +4,9 @@ check, and the result line.
 Everything that belongs to one configuration, traffic mix, cell or
 metric is a file of its own, found by the names in ``BENCHMARK.json``:
 
-* ``configs/<config>.json``: the model as run (published keys);
+* ``configs/<config>.json``: the model as run (published keys), with
+  the ``family`` whose module (``families/<family>.py``) gives its
+  shapes, engine config, weights, reference and counts;
 * ``traffic/<traffic>.json``: the mix, read by ``traffic.py``;
 * ``cells/<workload>.json``: the engine's sizes for that pair, the
   check's sample size and its limit;
@@ -12,8 +14,11 @@ metric is a file of its own, found by the names in ``BENCHMARK.json``:
   end-to-end or per-layer metric (``None``: nothing to read here, and
   the metric is left out of the line).
 
-So a later change adds a configuration, a mix, a cell or a metric as new
-files and an entry in ``BENCHMARK.json``, and edits nothing here.
+So a later change adds a family, a configuration, a mix, a cell or a
+metric as new files and an entry in ``BENCHMARK.json``, and edits
+nothing here. A cell's ``chips`` is the engine's head-shard count: the
+weights are replicated over the chips and the KV pool split over its KV
+heads.
 
 A run: make the weights from the seed on the device, build the engine,
 warm every program the cell's traffic drives, then serve waves back to
@@ -37,8 +42,7 @@ import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
-from benchmarks.chip import check, counts, spans, trace_reduce, traffic
-from benchmarks.chip import weights
+from benchmarks.chip import check, families, spans, trace_reduce, traffic
 
 HERE = pathlib.Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
@@ -67,6 +71,10 @@ class Cell:
     sizes: dict             # cells/<workload>.json
     end_to_end: List[dict]
     per_layer: List[dict]
+
+    @property
+    def family(self):
+        return families.of(self.config)
 
 
 def load_cell(name: str, bench_file=ROOT / "BENCHMARK.json") -> Cell:
@@ -133,8 +141,8 @@ class WaveRecord:
 class Run:
     """What the metric readers read."""
     cell: Cell
-    dims: counts.Dims
-    peak: dict
+    dims: object            # the family's shapes (``family.dims``)
+    peak: dict              # one chip's
     setup_s: float = 0.0
     window_s: float = 0.0
     waves: List[WaveRecord] = field(default_factory=list)
@@ -148,35 +156,45 @@ COUNTERS = ("new_tokens", "requests", "prefill_tokens_computed",
             "preemptions", "cow_copies")
 
 
-def arch_config(cfg: dict):
-    """The engine's ``ArchConfig`` for a configuration file."""
-    from repro.configs.base import ArchConfig
-    m = counts.Dims.of(cfg)
-    return ArchConfig(name=cfg["name"], family="dense", n_layers=m.layers,
-                      d_model=m.d, n_heads=m.heads, n_kv_heads=m.kv_heads,
-                      head_dim=m.head_dim, d_ff=m.d_ff, vocab=m.vocab,
-                      qkv_bias=m.qkv_bias, gated_mlp=True,
-                      tie_embeddings=m.tied,
-                      max_context=cfg["max_position_embeddings"])
+def weight_sharding(cell: Cell):
+    """Where the weights go: ``None`` (the default device) on one chip;
+    on more, replicated over the mesh the engine builds for
+    ``shards=chips`` (an equal mesh, so the engine's own placement of
+    the weights finds them in place)."""
+    if cell.chips == 1:
+        return None
+    import jax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.kernels.sharded import AXIS
+    from repro.sharding.rules import auto_mesh
+    mesh = auto_mesh((cell.chips,), (AXIS,),
+                     devices=jax.devices()[:cell.chips])
+    return NamedSharding(mesh, PartitionSpec())
 
 
 def build_engine(cell: Cell, params):
     from repro.models import RuntimeOptions
     from repro.serving.engine import ServeEngine
     s = cell.sizes
-    return ServeEngine(arch_config(cell.config), params=params,
+    return ServeEngine(cell.family.arch_config(cell.config), params=params,
                        opts=RuntimeOptions(dtype="bfloat16"),
                        scheduler="continuous",
                        max_batch=s["max_batch"], max_len=s["max_len"],
                        n_pages=s["n_pages"],
-                       prefill_budget=s.get("prefill_budget"))
+                       prefill_budget=s.get("prefill_budget"),
+                       shards=cell.chips)
 
 
 def serve(eng, seam: spans.Seam, prompts, max_new, sink: spans.Sink):
+    """The wave's outputs, and where its KV pool lay: (device id, shape)
+    of each chip's part of the k pool."""
     with seam.collecting(sink):
         outs = eng.serve_continuous(prompts, max_new)
+    layout = sorted((s.device.id, s.data.shape)
+                    for s in eng.pool["stack"]["k"].addressable_shards)
     eng.pool = None         # the next call builds its own pool
-    return outs
+    return outs, layout
 
 
 def warm_up(eng, seam: spans.Seam, cell: Cell, seed: int) -> None:
@@ -184,7 +202,9 @@ def warm_up(eng, seam: spans.Seam, cell: Cell, seed: int) -> None:
     the measured waves never use: the prefill chunk, the fused decode
     block at each step count a block can take (8, 4, 2, 1 with the
     default lookahead of 8), and the copy-on-write page copies at each
-    padded batch size up to twice the slots."""
+    padded batch size up to twice the slots, on a pool placed as the
+    engine places it."""
+    import jax
     import jax.numpy as jnp
     vocab = cell.config["vocab_size"]
     C, B = eng.prefill_chunk, eng.max_batch
@@ -198,6 +218,13 @@ def warm_up(eng, seam: spans.Seam, cell: Cell, seed: int) -> None:
         serve(eng, seam, prompts, n + 1, spans.Sink())
     from repro.models import init_paged_cache
     pool = init_paged_cache(eng.cfg, eng.n_pages, eng.page_size, eng.opts)
+    if eng.mesh is not None:
+        from jax.sharding import NamedSharding
+
+        from repro.sharding import rules
+        pool = jax.device_put(pool, jax.tree_util.tree_map(
+            lambda s: NamedSharding(eng.mesh, s),
+            rules.paged_cache_pspecs(pool, eng.mesh)))
     n = 1
     while n <= 2 * B:
         # built as the engine builds them: a list of (src, dst) pairs,
@@ -268,12 +295,14 @@ def _delta(after, before):
 
 
 def measure(cell: Cell, seed: int, seconds: float, trace: bool,
-            t_start: float, peak: dict, dev=None, compiles=None) -> dict:
-    """One run of a cell; returns the result line's fields."""
+            t_start: float, peak: dict, devices=(), compiles=None) -> dict:
+    """One run of a cell; returns the result line's fields. ``devices``:
+    the cell's chips, whose fullest is ``memory_peak_bytes``."""
     import jax
-    dims = counts.Dims.of(cell.config)
+    fam = cell.family
+    dims = fam.dims(cell.config)
     run = Run(cell=cell, dims=dims, peak=peak)
-    params = weights.served_params(dims, seed)
+    params = fam.served_params(dims, seed, sharding=weight_sharding(cell))
     jax.block_until_ready(params)
     with spans.stamped() as seam:
         eng = build_engine(cell, params)
@@ -295,8 +324,11 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             w = traffic.wave(cell.mix, cell.config["vocab_size"], seed, index)
             sink = spans.Sink(on_program=slicer)
             t_issue = time.perf_counter()
-            outs = serve(eng, seam, w.prompts, w.max_new_tokens, sink)
+            outs, layout = serve(eng, seam, w.prompts, w.max_new_tokens,
+                                 sink)
             t_end = time.perf_counter()
+            if not index:
+                say(f"pool_shards={layout}")
             if slicer is not None:
                 slicer.stop(sink)
             run.waves.append(WaveRecord(t_issue, t_end, w.prompts,
@@ -310,9 +342,9 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             f"setup_s={run.setup_s!r} compiles_in_window={c1[0] - c0[0]} "
             f"cache_loads_in_window={c1[1] - c0[1]} "
             f"counters={json.dumps(run.counters)}")
-    mem_peak = None
-    if dev is not None:
-        mem_peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use")
+             for d in devices]
+    mem_peak = max(peaks) if peaks and None not in peaks else None
     eng.params = eng.pool = None        # free the device before the check
     del eng
     gc.collect()
@@ -322,11 +354,12 @@ def measure(cell: Cell, seed: int, seconds: float, trace: bool,
             [trace_reduce.read(p) for p in trace_reduce.find(str(TRACE_DIR))],
             PROGRAMS)
         run.work = spans.work(wave0.events, slicer.ranges,
-                              [len(p) for p in wave0.prompts], dims)
+                              [len(p) for p in wave0.prompts], fam, dims)
         say(f"slices={len(slicer.ranges)} window_s={run.reduced.window_s!r} "
             f"busy_s={run.reduced.busy_s!r} "
             f"program_s={json.dumps(run.reduced.program_s)} "
             f"program_runs_trace={json.dumps(run.reduced.program_runs)} "
+            f"collective_ops={json.dumps(run.reduced.collective_ops)} "
             f"programs_noted={json.dumps(run.work.per_program)}")
     return finish(run, seed, trace, mem_peak)
 
@@ -351,7 +384,7 @@ def finish(run: Run, seed: int, trace: bool, mem_peak) -> dict:
     picked = check.sample(done, seed, chk["sample_tokens"])
     t0 = time.perf_counter()
     got = check.readings(cell.config, seed, [done[i] for i in picked],
-                         pad_to=check.reference.padded_len(
+                         pad_to=cell.family.padded_len(
                              cell.sizes["max_len"]))
     say(f"check: {len(picked)} requests, {got['tokens']} served tokens, "
         f"reference_s={time.perf_counter() - t0!r}")
@@ -406,7 +439,8 @@ def main(argv, t_start: float) -> int:
         f"compile_cache={use_compile_cache()} "
         f"bytes_limit={(dev.memory_stats() or {}).get('bytes_limit')}")
     out = measure(cell, args.seed, args.seconds, bool(args.trace), t_start,
-                  peaks[dev.device_kind], dev=dev, compiles=Compiles())
+                  peaks[dev.device_kind], devices=devices[:cell.chips],
+                  compiles=Compiles())
     if args.trace and args.keep_trace:
         shutil.copytree(TRACE_DIR, args.keep_trace, dirs_exist_ok=True)
     shutil.rmtree(TRACE_DIR, ignore_errors=True)

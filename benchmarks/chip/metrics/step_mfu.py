@@ -1,6 +1,7 @@
 """step_mfu: model operations the traced slices' prefill chunks and
-decode steps needed (``counts.py``), over the slices' wall time, as a
-share of the chip's bf16 peak. It bounds any one program's gain."""
+decode steps needed (the family's counts), over the slices' wall time,
+as a share of the cell's chips' bf16 peak (one chip's times ``chips``).
+It bounds any one program's gain."""
 
 
 def read(run):
@@ -10,4 +11,5 @@ def read(run):
     flops = w.prefill_flops + w.decode_flops
     if flops <= 0:
         return None
-    return 100.0 * flops / r.window_s / run.peak["bf16_flops_per_s"]
+    peak = run.cell.chips * run.peak["bf16_flops_per_s"]
+    return 100.0 * flops / r.window_s / peak

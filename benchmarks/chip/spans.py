@@ -13,16 +13,16 @@ else about the engine changes; the seam only holds while the recorder
 keeps these two methods and their arguments.
 
 ``Work`` turns the noted programs of the traced slices into the
-operations and bytes they needed (``counts.py``).
+operations and bytes they needed (the counts of the model's family,
+``families/``).
 """
 from __future__ import annotations
 
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
-
-from benchmarks.chip import counts
 
 PREFILL, DECODE = "prefill_chunk", "decode_block"
 
@@ -103,9 +103,10 @@ class Work:
 
 
 def work(events: Sequence[tuple], ranges: Sequence[Tuple[int, int]],
-         prompt_lens: Sequence[int], m: counts.Dims) -> Work:
+         prompt_lens: Sequence[int], family: ModuleType, m) -> Work:
     """Work of the programs whose events lie in ``ranges`` (half-open
-    index ranges into ``events``, one per traced slice).
+    index ranges into ``events``, one per traced slice), by the counts of
+    ``family`` at its shapes ``m``.
 
     A decode block's participants are the requests whose tokens follow
     it before the next program; request ``r`` with ``m0`` tokens before
@@ -132,14 +133,14 @@ def work(events: Sequence[tuple], ranges: Sequence[Tuple[int, int]],
         if i in inside and name == PREFILL:
             rid = args["rid"]
             s, e = args["tokens"]
-            w.prefill_flops += counts.prefill_flops(
+            w.prefill_flops += family.prefill_flops(
                 m, s, e, last=e == prompt_lens[rid])
         elif i in inside and name == DECODE:
             ctx = {r: prompt_lens[r] + emitted.get(r, 0) for r in block}
             for step in range(max(block.values(), default=0)):
                 live = [ctx[r] + step for r, q in block.items() if q > step]
-                w.decode_bytes += counts.decode_step_bytes(m, live)
-                w.decode_flops += sum(counts.decode_flops(m, c) for c in live)
+                w.decode_bytes += family.decode_step_bytes(m, live)
+                w.decode_flops += sum(family.decode_flops(m, c) for c in live)
         w.per_program[name] = w.per_program.get(name, 0) + (i in inside)
         for r, q in block.items():
             emitted[r] = emitted.get(r, 0) + q

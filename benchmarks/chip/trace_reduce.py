@@ -174,6 +174,24 @@ def _is_leaf(op: str) -> bool:
     return kind not in ("while", "conditional", "call")
 
 
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+
+def is_collective(op: str) -> bool:
+    """An exchange between chips, by its HLO op kind (the last word of a
+    ``_short`` name; its instruction name where a tuple type left the
+    kind out), asynchronous ``-start`` and ``-done`` halves included."""
+    lhs, _, rhs = op.partition(" = ")
+    words = [lhs.lstrip("%").split(".")[0]] + rhs.split()[-1:]
+    for w in words:
+        for suffix in ("-start", "-done"):
+            w = w[:-len(suffix)] if w.endswith(suffix) else w
+        if w in COLLECTIVES:
+            return True
+    return False
+
+
 SKEW_S = 0.002   # device events may sit this far before their dispatch
 
 
@@ -269,8 +287,10 @@ class Reduced:
     """What the per-layer readers take from the traces of one run."""
     window_s: float = 0.0
     busy_s: float = 0.0                     # averaged over chips
-    program_s: Dict[str, float] = field(default_factory=dict)
-    program_runs: Dict[str, int] = field(default_factory=dict)
+    collective_s: float = 0.0               # averaged over chips
+    program_s: Dict[str, float] = field(default_factory=dict)  # averaged
+    program_runs: Dict[str, int] = field(default_factory=dict)  # one chip's
+    collective_ops: Dict[str, float] = field(default_factory=dict)  # first
     device_ops: List[List[object]] = field(default_factory=list)
     idle_gaps: List[List[object]] = field(default_factory=list)
 
@@ -278,7 +298,9 @@ class Reduced:
 def reduce(traces: Sequence[Trace], programs: Sequence[str],
            top: int = 10) -> Reduced:
     """Busy and idle time, per-program device time and the breakdown
-    over every slice of every trace (each trace on its own clock)."""
+    over every slice of every trace (each trace on its own clock).
+    Seconds are averaged over the chips; a program's runs are those of
+    one chip, since every chip runs each program of the mesh once."""
     r = Reduced()
     per_op: Dict[str, float] = {}
     labelled: List[List[object]] = []
@@ -289,14 +311,20 @@ def reduce(traces: Sequence[Trace], programs: Sequence[str],
         for chip in chips:
             iv = [(s, e) for _, s, e in tr.ops[chip]]
             r.busy_s += busy_seconds(iv, win) / len(chips)
+            coll = [(s, e) for n, s, e in tr.ops[chip] if is_collective(n)]
+            r.collective_s += busy_seconds(coll, win) / len(chips)
             for p in programs:
                 secs, n = program_seconds(tr.modules.get(chip, []), p, win)
                 r.program_s[p] = r.program_s.get(p, 0.0) + secs / len(chips)
-                r.program_runs[p] = r.program_runs.get(p, 0) + n
+                if chip == chips[0]:
+                    r.program_runs[p] = r.program_runs.get(p, 0) + n
         if chips:
             first = qualify(tr.ops[chips[0]], tr.modules.get(chips[0], []))
             for name, t in op_seconds(first, win).items():
                 per_op[name] = per_op.get(name, 0.0) + t
+                if is_collective(name.rpartition("/")[2]):
+                    r.collective_ops[name] = (r.collective_ops.get(name, 0.0)
+                                              + t)
             idle = gaps([(s, e) for _, s, e in first], win)
             labelled += label_gaps(idle, tr.host, top)
     r.device_ops = [[n, t] for n, t in

@@ -105,8 +105,10 @@ def _dense(w, b=None):
     return {"w": w} if b is None else {"w": w, "b": b}
 
 
-def served_params(m: Dims, seed: int):
-    """The engine's parameter pytree for ``seed``, in one jitted call."""
+def served_params(m: Dims, seed: int, *, sharding=None):
+    """The engine's parameter pytree for ``seed``, in one jitted call;
+    with ``sharding`` (replicated over the engine's mesh) every chip
+    draws its own copy, so no chip ever holds two."""
 
     def make(key):
         g = global_leaves(m, key)
@@ -124,4 +126,6 @@ def served_params(m: Dims, seed: int):
             params["lm_head"] = _dense(g["head"])
         return params
 
-    return jax.jit(make)(seed_key(seed))
+    if sharding is None:
+        return jax.jit(make)(seed_key(seed))
+    return jax.jit(make, out_shardings=sharding)(seed_key(seed))

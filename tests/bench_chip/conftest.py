@@ -12,7 +12,7 @@ for p in (str(ROOT), str(ROOT / "src")):
 def tiny_config(tied: bool = True) -> dict:
     """A configuration file's keys at a size the CPU serves in seconds
     (head_dim 32 keeps the engine on its XLA path)."""
-    return {"name": "tiny", "hidden_size": 128, "intermediate_size": 256,
+    return {"name": "tiny", "family": "dense", "hidden_size": 128, "intermediate_size": 256,
             "max_position_embeddings": 4096, "num_attention_heads": 4,
             "num_hidden_layers": 4, "num_key_value_heads": 2,
             "rms_norm_eps": 1e-6, "rope_theta": 10000.0,

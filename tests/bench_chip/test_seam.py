@@ -8,6 +8,7 @@ import sys
 from conftest import ROOT, tiny_cell
 
 from benchmarks.chip import counts, harness, spans, traffic, weights
+from benchmarks.chip.families import dense
 
 
 def _serve(eng, w, sink=None):
@@ -70,7 +71,7 @@ def test_work_of_the_traced_programs_by_hand():
           ("program", 0.5, D, {"n_steps": 1}),
           ("token", 0.6, 0)]
     # traced: the second chunk and the first decode block only
-    w = spans.work(ev, [(1, 2), (3, 4)], [40], m)
+    w = spans.work(ev, [(1, 2), (3, 4)], [40], dense, m)
     assert w.prefill_flops == counts.prefill_flops(m, 32, 40, last=True)
     # the block's steps attend over 41 and 42 positions (prompt 40, one
     # token emitted before it)

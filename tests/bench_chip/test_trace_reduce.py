@@ -95,7 +95,8 @@ def test_reduce_averages_busy_over_chips():
     assert r.window_s == pytest.approx(20.0)
     assert r.busy_s == pytest.approx(2 * 3.0)
     assert r.program_s["p"] == pytest.approx(6.0)
-    assert r.program_runs["p"] == 4
+    # runs of one chip: each chip runs every program of the mesh once
+    assert r.program_runs["p"] == 2
     assert r.device_ops == [["p/%a", pytest.approx(8.0)]]
     assert r.idle_gaps[0] == ["h", pytest.approx(6.0)]
 
@@ -122,3 +123,92 @@ def test_recorded_v5e_trace():
     assert all(n.split("/")[0] in ("prefill_paged_chunk",
                                    "decode_steps_paged")
                for n, _ in r.device_ops)
+
+
+def test_four_chip_trace_counts_one_chip_runs_and_averages_busy():
+    # each chip runs the mesh's programs once: two prefill runs and one
+    # decode run on every one of four chips, busy 3, 4, 5 and 6 s
+    def chip(busy):
+        return [("%fusion.1 = bf16[2] fusion", 0.0, busy)]
+    mods = [("prefill_paged_chunk", 0.0, 1.0),
+            ("prefill_paged_chunk", 1.0, 2.0),
+            ("decode_steps_paged", 2.0, 3.0)]
+    t = tr.Trace(windows=[(0.0, 10.0)],
+                 ops={c: chip(3.0 + c) for c in range(4)},
+                 modules={c: list(mods) for c in range(4)})
+    r = tr.reduce([t], ["prefill_paged_chunk", "decode_steps_paged"])
+    assert r.program_runs == {"prefill_paged_chunk": 2,
+                              "decode_steps_paged": 1}
+    assert r.busy_s == pytest.approx((3 + 4 + 5 + 6) / 4)
+    assert r.program_s["prefill_paged_chunk"] == pytest.approx(2.0)
+    assert r.collective_s == 0.0
+
+
+@pytest.mark.parametrize("op,want", [
+    ("%all-gather.3 = bf16[1,32,32,128] all-gather", True),
+    ("%all-gather-start.1 = ", True),        # a tuple type left no kind
+    ("%ag = bf16[8] all-gather-done", True),
+    ("%all-reduce.2 = f32[8] all-reduce", True),
+    ("%reduce-scatter.1 = f32[2] reduce-scatter", True),
+    ("%all-to-all = f32[8] all-to-all", True),
+    ("%collective-permute-start.4 = ", True),
+    ("%fusion.12 = bf16[8,128] fusion", False),
+    ("%copy.3 = bf16[8] copy", False),
+    ("%all-gatherish = f32[2] custom-call", False),
+])
+def test_collectives_by_op_kind(op, want):
+    assert tr.is_collective(op) is want
+
+
+def test_collective_share_by_hand():
+    from types import SimpleNamespace
+
+    from benchmarks.chip import harness
+    read = harness.reader("collective_share")
+    # two chips in a 10 s slice. Chip 0: compute 0-4 s, an all-gather
+    # 4-5 s, its async done half 4.5-5.5 s (overlapping: 1.5 s of
+    # collectives), compute 6-8 s; busy 7.5 s. Chip 1: compute 0-5 s, an
+    # all-reduce 5-5.5 s; busy 5.5 s.
+    t = tr.Trace(windows=[(0.0, 10.0)], ops={
+        0: [("%fusion.1 = bf16[2] fusion", 0.0, 4.0),
+            ("%all-gather-start.1 = ", 4.0, 5.0),
+            ("%all-gather-done.1 = bf16[4] all-gather-done", 4.5, 5.5),
+            ("%fusion.2 = bf16[2] fusion", 6.0, 8.0)],
+        1: [("%fusion.1 = bf16[2] fusion", 0.0, 5.0),
+            ("%all-reduce.1 = f32[2] all-reduce", 5.0, 5.5)]})
+    r = tr.reduce([t], [])
+    assert r.busy_s == pytest.approx((7.5 + 5.5) / 2)
+    assert r.collective_s == pytest.approx((1.5 + 0.5) / 2)
+    assert r.collective_ops == {"%all-gather-start.1 = ": pytest.approx(1.0),
+                                "%all-gather-done.1 = bf16[4] all-gather-done":
+                                pytest.approx(1.0)}
+    run = SimpleNamespace(reduced=r)
+    assert read(run) == pytest.approx(100.0 * 1.0 / 6.5)
+    # no collective, or no trace: nothing to read
+    assert read(SimpleNamespace(reduced=tr.reduce(
+        [tr.Trace(windows=[(0.0, 1.0)],
+                  ops={0: [("%fusion.1 = f32[2] fusion", 0.0, 0.5)]})],
+        []))) is None
+    assert read(SimpleNamespace(reduced=None)) is None
+
+
+def test_roofline_readers_divide_by_chips_times_one_chips_peak():
+    from types import SimpleNamespace
+
+    from benchmarks.chip import harness, spans
+    peak = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
+    work = spans.Work(prefill_flops=4e11, decode_flops=1e11,
+                      decode_bytes=2e11)
+    reduced = tr.Reduced(window_s=10.0, busy_s=8.0,
+                         program_s={"prefill_paged_chunk": 2.0,
+                                    "decode_steps_paged": 4.0})
+
+    def reads(chips):
+        run = SimpleNamespace(cell=SimpleNamespace(chips=chips), peak=peak,
+                              reduced=reduced, work=work)
+        return [harness.reader(n)(run)
+                for n in ("step_mfu", "prefill_mfu", "decode_roofline")]
+    # one chip: 5e11 / 10 s / 1e12; 4e11 / 2 s / 1e12; 2e11 B / 1e11 / 4 s
+    assert reads(1) == [pytest.approx(5.0), pytest.approx(20.0),
+                        pytest.approx(50.0)]
+    assert reads(4) == [pytest.approx(v / 4) for v in reads(1)]

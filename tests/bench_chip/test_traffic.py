@@ -20,7 +20,7 @@ def _in(dist, n):
 
 
 def test_lengths_in_range_for_every_mix():
-    for name in ("longctx", "docqa", "chat"):
+    for name in ("longctx", "docqa", "chat", "docbatch"):
         mix = _mix(name)
         for index in range(4):
             w = traffic.wave(mix, 1000, BIG_SEED, index)
@@ -44,7 +44,7 @@ def test_same_seed_same_waves():
 
 
 def test_other_seed_same_sizes_same_order():
-    for name in ("longctx", "docqa", "chat"):
+    for name in ("longctx", "docqa", "chat", "docbatch"):
         mix = _mix(name)
         a = traffic.wave(mix, 1000, 7, 0)
         b = traffic.wave(mix, 1000, BIG_SEED, 0)
@@ -90,3 +90,19 @@ def test_every_cell_stays_within_its_model_and_slots():
         assert longest <= cfg["max_position_embeddings"], wl["name"]
         assert longest <= cell["max_len"], wl["name"]
         assert len(w.prompts) <= cell["max_batch"], wl["name"]
+
+
+def test_docbatch_stays_within_yi6b_positions_with_the_same_sizes():
+    mix = _mix("docbatch")
+    want = None
+    for seed in (0, 7, BIG_SEED, 2**40 + 1):
+        w = traffic.wave(mix, 64000, seed, 0)
+        assert set(w.doc_of) == {-1}            # nothing shared
+        sizes = list(map(len, w.prompts))
+        assert len(sizes) == 12 and w.max_new_tokens == 256
+        # yi-6b publishes 4096 positions: the longest prompt and its output
+        assert max(sizes) + w.max_new_tokens <= 4096
+        assert min(sizes) >= 2048
+        want = want or sizes
+        assert sizes == want
+    assert sum(want) == 34_206
